@@ -1573,6 +1573,9 @@ def write_bench_json(which, durations) -> str:
 
 
 def main() -> None:
+    from repro.runtime.platform import enable_compile_cache
+
+    enable_compile_cache()
     args = sys.argv[1:]
     FLAGS.update(a for a in args if a.startswith("--"))
     which = [a for a in args if not a.startswith("--")] or list(TABLES)

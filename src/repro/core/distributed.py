@@ -28,23 +28,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax ≥ 0.6 exposes shard_map at the top level
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-
-
-def _mark_varying(x, axes):
-    """pcast-to-varying where the API exists (jax ≥ 0.7, where shard_map
-    checks that scan carries stay replicated otherwise); no-op before."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to="varying")
-    return x
 
 from repro.core.scoring import score_same
 from repro.core.types import CopyConfig
+from repro.kernels.copyscore import zeros_varying_like
 from repro.kernels.ops import copyscore_tile_fused
 
 
@@ -84,8 +73,10 @@ def _local_tile_scores(v_skw, acc, p_hat, delta, nout_blk, coords, *, tile,
             impl=impl, delta_blk=delta, nout_blk=nout_blk)
 
     def skip(rc):
-        del rc
-        return (jnp.zeros((tile, tile), jnp.float32),) * 5
+        # typed like compute's outputs: lax.cond needs both branches to
+        # vary over the same mesh axes
+        return (zeros_varying_like((tile, tile), v_skw, acc, p_hat, delta,
+                                   nout_blk, rc),) * 5
 
     def one_tile(_, rc):
         return 0, jax.lax.cond(rc[0] >= 0, compute, skip, rc)
@@ -109,7 +100,6 @@ def sharded_tile_scores(
     impl: str = "auto",
     block_i: int = 128,
     block_j: int = 128,
-    donate: bool = False,
 ):
     """Shard surviving pair tiles over a 1-D mesh; returns stacked tiles.
 
@@ -122,10 +112,6 @@ def sharded_tile_scores(
     (lax.cond) instead of recomputing a real tile. Output: five
     (n_tiles_padded, T, T) arrays (C_same→, C_same←, count, count outside
     Ē, error bound).
-
-    ``donate=True`` donates the v-slab buffer to the call (the prefetched
-    double-buffered stream never reuses a group's slab, so XLA may recycle
-    it in place). Keep it off on CPU — unusable-donation warnings.
     """
     axis = mesh.axis_names[0]
     n_dev = mesh.shape[axis]
@@ -139,8 +125,7 @@ def sharded_tile_scores(
         coords = np.concatenate([coords,
                                  np.full((pad, 2), -1, coords.dtype)])
 
-    fn = _sharded_tile_fn(mesh, tile, cfg.s, cfg.n, impl, block_i, block_j,
-                          donate)
+    fn = _sharded_tile_fn(mesh, tile, cfg.s, cfg.n, impl, block_i, block_j)
     return fn(jnp.asarray(v_skw), jnp.asarray(acc, jnp.float32),
               jnp.asarray(p_hat, jnp.float32),
               jnp.asarray(delta, jnp.float32),
@@ -150,13 +135,12 @@ def sharded_tile_scores(
 
 @functools.lru_cache(maxsize=64)
 def _sharded_tile_fn(mesh: Mesh, tile: int, s: float, n: float, impl: str,
-                     block_i: int, block_j: int, donate: bool = False):
+                     block_i: int, block_j: int):
     """Cached jitted shard_map for the tile scan.
 
     The engine streams chunk groups through this in a host loop, so the
     compiled executable MUST be reused across calls — a fresh
     ``jax.jit(shard_map(...))`` per group would retrace every time.
-    ``donate`` releases the v-slab argument's buffer to XLA (argument 0).
     """
     axis = mesh.axis_names[0]
     local = partial(_local_tile_scores, tile=tile, s=s, n=n,
@@ -165,7 +149,7 @@ def _sharded_tile_fn(mesh: Mesh, tile: int, s: float, n: float, impl: str,
         local, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(axis)),
         out_specs=(P(axis),) * 5,
-    ), donate_argnums=(0,) if donate else ())
+    ))
 
 
 # ---------------------------------------------------------------------------
@@ -233,7 +217,8 @@ def sharded_tile_scores_2d(
                                  np.full((pad, 2), -1, coords.dtype)])
     fn = _sharded_tile_fn_2d(mesh, tile, cfg.s, cfg.n, impl,
                              block_i, block_j)
-    return fn(jnp.asarray(v_skw), jnp.asarray(acc, jnp.float32),
+    v_dev = jax.device_put(v_skw, NamedSharding(mesh, P(None, p_axis, None)))
+    return fn(v_dev, jnp.asarray(acc, jnp.float32),
               jnp.asarray(p_hat), jnp.asarray(delta), jnp.asarray(nout),
               jnp.asarray(coords, jnp.int32))
 
@@ -284,7 +269,8 @@ def _local_pair_scores(vr, vc, acc_r, acc_c, p_hat, s, n, has_pod):
     S_c = vc.shape[0]
     # the accumulators are device-varying over the pair-tile axes — mark them
     varying = ("data", "model") + (("pod",) if has_pod else ())
-    zero = _mark_varying(jnp.zeros((S_r, S_c), jnp.float32), varying)
+    zero = jax.lax.pcast(jnp.zeros((S_r, S_c), jnp.float32), varying,
+                         to="varying")
     (c_same, n_cnt), _ = jax.lax.scan(
         body, (zero, zero), (jnp.moveaxis(vr, 1, 0), jnp.moveaxis(vc, 1, 0), p_hat))
     if has_pod:

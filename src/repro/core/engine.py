@@ -57,7 +57,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import tilecache
 from repro.core.bound import bound_detect
@@ -593,7 +593,7 @@ class DetectionEngine:
                                    slack=self.DELTA_SLACK)
 
     def _tile_kernel(self, v_dev, acc_vec, p_g, coords_g, T, d_g, o_g,
-                     block, donate=False):
+                     block):
         """One group pass: 1-D tile mesh, or data×pod when mesh_shape is set."""
         opt = self.options
         if opt.mesh_shape is not None:
@@ -604,29 +604,22 @@ class DetectionEngine:
         return sharded_tile_scores(
             self.mesh(), v_dev, acc_vec, p_g, coords_g, self.cfg, tile=T,
             delta=d_g, nout=o_g, impl=opt.kernel_impl,
-            block_i=block, block_j=block, donate=donate)
+            block_i=block, block_j=block)
 
     def _stage_v(self, v_np, dtype):
-        """Host→device conversion of one group's v-slab.
+        """Host→device transfer of one group's v-slab, replicated over every
+        device of the 1-D tile mesh (not only the default device).
 
         Runs on the prefetch thread when ``prefetch_depth`` ≥ 1, so the
         transfer of group G+1 hides behind group G's kernel. The 2-D
         (``mesh_shape``) path pod-pads the chunk axis host-side inside
-        ``sharded_tile_scores_2d`` — v stays host-resident there and only
-        the (dominant) host assembly is overlapped.
+        ``sharded_tile_scores_2d``, which then places the pod shards — v
+        stays host-resident here and only the host assembly is overlapped.
         """
+        v = np.asarray(v_np, np.dtype(dtype))
         if self.options.mesh_shape is not None:
-            return (v_np if dtype == jnp.int8
-                    else jnp.asarray(v_np, dtype=dtype))
-        return jnp.asarray(v_np, dtype=dtype)
-
-    def _donate_ok(self) -> bool:
-        """Donate staged v-slabs to the kernel? Only when the pipeline is
-        double-buffering fresh per-group device arrays on the 1-D mesh —
-        and never on CPU, where XLA can't use the donation and warns."""
-        return (self.options.prefetch_depth > 0
-                and self.options.mesh_shape is None
-                and jax.default_backend() != "cpu")
+            return v
+        return jax.device_put(v, NamedSharding(self.mesh(), P()))
 
     # scatter lives in shardplan (shared with OwnerPartial.to_grids); the
     # staticmethod survives for callers that patched/tuned it per engine
@@ -727,13 +720,12 @@ class DetectionEngine:
                 o_g[i] = ech.nout[k]
             return self._stage_v(v_np, dtype), p_g, d_g, o_g, coords_g
 
-        donate = self._donate_ok()
         pf = ChunkPrefetcher(groups, _stage,
                              depth=self.options.prefetch_depth)
         try:
             for v_dev, p_g, d_g, o_g, coords_g in pf:
                 outs = self._tile_kernel(v_dev, acc_slab, p_g, coords_g, T,
-                                         d_g, o_g, block, donate=donate)
+                                         d_g, o_g, block)
                 stacks = (list(outs) if stacks is None
                           else [st + o for st, o in zip(stacks, outs)])
         finally:
@@ -964,13 +956,11 @@ class DetectionEngine:
                     o_g[i] = ech.nout[k]
                 return self._stage_v(v_np, dtype), p_g, d_g, o_g, coords_g
 
-            donate = self._donate_ok()
             pf = ChunkPrefetcher(groups, _stage, depth=opt.prefetch_depth)
             try:
                 for v_dev, p_g, d_g, o_g, coords_g in pf:
                     outs = self._tile_kernel(v_dev, acc_pad, p_g, coords_g,
-                                             T, d_g, o_g, block,
-                                             donate=donate)
+                                             T, d_g, o_g, block)
                     stacks = (list(outs) if stacks is None
                               else [s + o for s, o in zip(stacks, outs)])
             finally:

@@ -98,8 +98,10 @@ def rescore_pairs_exact(
     """
     if len(pi) == 0:
         return 0
-    c_fwd[pi, pj] = pair_scores_subset(ds, p_claim, cfg, pi, pj)
-    c_fwd[pj, pi] = pair_scores_subset(ds, p_claim, cfg, pj, pi)
+    both = pair_scores_subset(ds, p_claim, cfg, np.concatenate([pi, pj]),
+                              np.concatenate([pj, pi]))
+    c_fwd[pi, pj] = both[:len(pi)]
+    c_fwd[pj, pi] = both[len(pi):]
     return len(pi)
 
 

@@ -273,6 +273,22 @@ def _entry_scores_vectorized(
     return score_same_np(p.astype(np.float64), a1, a2, cfg.s, cfg.n).astype(np.float32)
 
 
+def _shared_item_counts(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    """Exact shared-item counts ``a @ b.T`` of 0/1 provenance rows, int32.
+
+    Runs as a float32 BLAS matmul. Every partial sum is a count of items
+    both rows provide, so it is an integer no larger than either row's
+    item count and stays exact while that is below 2**24.
+    """
+    most = max(int(a.sum(axis=1).max(initial=0)),
+               int(b.sum(axis=1).max(initial=0)))
+    if most >= 1 << 24:
+        raise ValueError(
+            f"a source provides {most} items; float32 shared-item counts "
+            f"are exact only below 2**24")
+    return (a.astype(np.float32) @ b.astype(np.float32).T).astype(np.int32)
+
+
 def build_index(
     ds: ClaimsDataset,
     p_claim: np.ndarray,
@@ -366,8 +382,7 @@ def build_index(
     # Ē — maximal low-score suffix with Σ C(E) < ln(β/2α)
     ebar_start = _ebar_boundary(entry_score, cfg.theta_ind)
 
-    prov64 = prov.astype(np.int64)
-    l_counts = (prov64 @ prov64.T).astype(np.int32)
+    l_counts = _shared_item_counts(prov, prov)
 
     return InvertedIndex(
         store=store,
@@ -614,14 +629,14 @@ def commit_rows(
     # -- 4. block updates of the pair/source aggregates ---------------------
     if q:
         prov = ds.provided_mask
-        prov_old = prov[:S0].astype(np.int64)
-        prov_new = prov[S0:].astype(np.int64)
+        prov_old = prov[:S0]
+        prov_new = prov[S0:]
         l_new = np.zeros((S, S), np.int32)
         l_new[:S0, :S0] = index.l_counts
-        cross = (prov_old @ prov_new.T).astype(np.int32)
+        cross = _shared_item_counts(prov_old, prov_new)
         l_new[:S0, S0:] = cross
         l_new[S0:, :S0] = cross.T
-        l_new[S0:, S0:] = (prov_new @ prov_new.T).astype(np.int32)
+        l_new[S0:, S0:] = _shared_item_counts(prov_new, prov_new)
         index.l_counts = l_new
         index.items_per_source = np.concatenate(
             [index.items_per_source,

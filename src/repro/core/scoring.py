@@ -196,6 +196,13 @@ def pairwise_detect(
     )
 
 
+#: Most pairs scored per device call. Each call gathers (pairs, items)
+#: claim rows, so the block bounds device memory (a Book-full batch can
+#: rescore ~650 k pairs × 20 k items, 52 GB gathered at once); shorter
+#: lists pad to a power of two, so few shapes are ever compiled.
+PAIR_BLOCK = 4096
+
+
 def pair_scores_subset(
     ds: ClaimsDataset,
     p_claim: np.ndarray,
@@ -205,12 +212,18 @@ def pair_scores_subset(
 ) -> np.ndarray:
     """Exact C→ for an explicit list of pairs (used for near-threshold
     rescoring by the bucketed algorithms). Returns (n_pairs,) C→[i, j]."""
+    n_pairs = len(pairs_i)
+    block = min(PAIR_BLOCK, 1 << max(n_pairs - 1, 0).bit_length())
+    pad = (-n_pairs) % block
+    pi = np.pad(np.asarray(pairs_i, np.int32), (0, pad))
+    pj = np.pad(np.asarray(pairs_j, np.int32), (0, pad))
     vals = jnp.asarray(ds.values)
     p = jnp.asarray(p_claim, dtype=jnp.float32)
     acc = jnp.asarray(ds.accuracy, dtype=jnp.float32)
-    return np.asarray(
-        _pair_list_scores(vals, p, acc, jnp.asarray(pairs_i), jnp.asarray(pairs_j), cfg.s, cfg.n)
-    )
+    out = [_pair_list_scores(vals, p, acc, jnp.asarray(pi[k:k + block]),
+                             jnp.asarray(pj[k:k + block]), cfg.s, cfg.n)
+           for k in range(0, len(pi), block)]
+    return np.concatenate([np.asarray(o) for o in out])[:n_pairs]
 
 
 @partial(jax.jit, static_argnames=("s", "n"))

@@ -48,6 +48,47 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+
+def _operand_vma(*operands) -> frozenset:
+    """Mesh axes any operand varies over (empty outside ``shard_map``).
+
+    Inside ``shard_map`` a value built from constants is replicated, while
+    a result computed from varying operands varies over their axes. Kernel
+    outputs and scan carries must carry that union, or the type check
+    rejects the mismatch.
+    """
+    return frozenset().union(*(jax.typeof(x).vma for x in operands))
+
+
+def _vary_all(*operands):
+    """Cast every operand to vary over the union of their mesh axes, so the
+    kernel body's elementwise ops (evaluated directly in interpret mode)
+    see operands of one type; returns the operands and that union."""
+    vma = _operand_vma(*operands)
+    return [jax.lax.pcast(x, tuple(vma - jax.typeof(x).vma), to="varying")
+            for x in operands], vma
+
+
+def zeros_varying_like(shape, *operands):
+    """f32 zeros that vary over every mesh axis ``operands`` vary over: the
+    start of an accumulator (or a skipped branch's result) that must type
+    like values computed from those operands."""
+    return jax.lax.pcast(jnp.zeros(shape, jnp.float32),
+                         tuple(_operand_vma(*operands)), to="varying")
+
+
+def _interpret_params(interpret: bool):
+    """``interpret=True`` runs the TPU interpreter, which simulates SMEM and
+    VMEM and, unlike the generic one, evaluates inside ``shard_map``."""
+    return pltpu.InterpretParams() if interpret else False
+
+
+#: Per-entry-block scalars (p̂, δ, non-Ē flag) live whole in SMEM and are
+#: read at ``pl.program_id(2)``: a (1, 1) VMEM block of an (n_e, 1) array
+#: is not (8, 128)-tiled, which Mosaic refuses once n_e > 1.
+_BLOCK_SCALARS = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _count_matmul(vi, vj):
@@ -75,7 +116,7 @@ def _copyscore_kernel(p_ref, vi_ref, vj_ref, ai_ref, aj_ref,
 
     count = _count_matmul(vi_ref[...], vj_ref[...])    # (bi, bj) on the MXU
 
-    p = p_ref[0, 0]
+    p = p_ref[e]
     a1 = ai_ref[...].astype(jnp.float32)               # (bi, 1) copier accuracy
     a2 = aj_ref[...].astype(jnp.float32).reshape(1, -1)  # (1, bj) source accuracy
     pr_src = p * a2 + (1.0 - p) * (1.0 - a2)
@@ -102,7 +143,7 @@ def _copyscore_err_kernel(p_ref, d_ref, vi_ref, vj_ref, ai_ref, aj_ref,
 
     count = _count_matmul(vi_ref[...], vj_ref[...])
 
-    p = p_ref[0, 0]
+    p = p_ref[e]
     a1 = ai_ref[...].astype(jnp.float32)
     a2 = aj_ref[...].astype(jnp.float32).reshape(1, -1)
     pr_src = p * a2 + (1.0 - p) * (1.0 - a2)
@@ -111,7 +152,7 @@ def _copyscore_err_kernel(p_ref, d_ref, vi_ref, vj_ref, ai_ref, aj_ref,
 
     c_ref[...] += f * count
     n_ref[...] += count
-    err_ref[...] += d_ref[0, 0] * count
+    err_ref[...] += d_ref[e] * count
 
 
 @functools.partial(
@@ -150,43 +191,30 @@ def copyscore_pallas(
     assert E % block_e == 0, (E, block_e)
     n_e = E // block_e
 
-    p2 = p_blk.reshape(n_e, 1).astype(jnp.float32)
+    scalars = [p_blk] if delta_blk is None else [p_blk, delta_blk]
+    scalars = [x.reshape(n_e).astype(jnp.float32) for x in scalars]
     a_i = acc.reshape(S_i, 1).astype(jnp.float32)
     a_j = accj.reshape(S_j, 1).astype(jnp.float32)
+    (v, vj, a_i, a_j, *scalars), vma = _vary_all(v, vj, a_i, a_j, *scalars)
 
     grid = (S_i // block_i, S_j // block_j, n_e)
-    scalar_spec = pl.BlockSpec((1, 1), lambda i, j, e: (e, 0))
-    in_specs = [
-        scalar_spec,                                             # p̂
+    in_specs = [_BLOCK_SCALARS] * len(scalars) + [               # p̂[, δ]
         pl.BlockSpec((block_i, block_e), lambda i, j, e: (i, e)),  # V rows
         pl.BlockSpec((block_j, block_e), lambda i, j, e: (j, e)),  # V cols
         pl.BlockSpec((block_i, 1), lambda i, j, e: (i, 0)),      # A_i
         pl.BlockSpec((block_j, 1), lambda i, j, e: (j, 0)),      # A_j
     ]
     out_spec = pl.BlockSpec((block_i, block_j), lambda i, j, e: (i, j))
-    out_sds = jax.ShapeDtypeStruct((S_i, S_j), jnp.float32)
-
-    if delta_blk is None:
-        kernel = functools.partial(_copyscore_kernel, s=float(s),
-                                   n_false=float(n_false))
-        c, n = pl.pallas_call(
-            kernel, grid=grid, in_specs=in_specs,
-            out_specs=[out_spec, out_spec], out_shape=[out_sds, out_sds],
-            interpret=interpret,
-        )(p2, v, vj, a_i, a_j)
-        return c, n
-
-    d2 = delta_blk.reshape(n_e, 1).astype(jnp.float32)
-    kernel = functools.partial(_copyscore_err_kernel, s=float(s),
-                               n_false=float(n_false))
-    c, n, err = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[in_specs[0], scalar_spec] + in_specs[1:],
-        out_specs=[out_spec, out_spec, out_spec],
-        out_shape=[out_sds, out_sds, out_sds],
-        interpret=interpret,
-    )(p2, d2, v, vj, a_i, a_j)
-    return c, n, err
+    out_sds = jax.ShapeDtypeStruct((S_i, S_j), jnp.float32, vma=vma)
+    n_out = 1 + len(scalars)                                     # C, n[, err]
+    kernel = functools.partial(
+        _copyscore_kernel if delta_blk is None else _copyscore_err_kernel,
+        s=float(s), n_false=float(n_false))
+    return tuple(pl.pallas_call(
+        kernel, grid=grid, in_specs=in_specs,
+        out_specs=[out_spec] * n_out, out_shape=[out_sds] * n_out,
+        interpret=_interpret_params(interpret),
+    )(*scalars, v, vj, a_i, a_j))
 
 
 def _copyscore_fused_kernel(p_ref, d_ref, m_ref, vi_ref, vj_ref, ai_ref, aj_ref,
@@ -213,7 +241,7 @@ def _copyscore_fused_kernel(p_ref, d_ref, m_ref, vi_ref, vj_ref, ai_ref, aj_ref,
 
     count = _count_matmul(vi_ref[...], vj_ref[...])    # (bi, bj)
 
-    p = p_ref[0, 0]
+    p = p_ref[e]
     a1 = ai_ref[...].astype(jnp.float32)               # (bi, 1) row accuracy
     a2 = aj_ref[...].astype(jnp.float32).reshape(1, -1)  # (1, bj) col accuracy
     # pr_ind associates the accuracy products symmetrically (a1·a2 first), so
@@ -226,8 +254,8 @@ def _copyscore_fused_kernel(p_ref, d_ref, m_ref, vi_ref, vj_ref, ai_ref, aj_ref,
     cf_ref[...] += f_fwd * count
     cb_ref[...] += f_bwd * count
     n_ref[...] += count
-    o_ref[...] += m_ref[0, 0] * count                  # non-Ē blocks only
-    e_ref[...] += d_ref[0, 0] * count
+    o_ref[...] += m_ref[e] * count                     # non-Ē blocks only
+    e_ref[...] += d_ref[e] * count
 
 
 @functools.partial(
@@ -267,32 +295,33 @@ def copyscore_fused_pallas(
     assert E % block_e == 0, (E, block_e)
     n_e = E // block_e
 
-    p2 = p_blk.reshape(n_e, 1).astype(jnp.float32)
+    p1 = p_blk.reshape(n_e).astype(jnp.float32)
     d_blk = jnp.zeros(n_e) if delta_blk is None else delta_blk
     m_blk = jnp.ones(n_e) if nout_blk is None else nout_blk
-    d2 = d_blk.reshape(n_e, 1).astype(jnp.float32)
-    m2 = m_blk.reshape(n_e, 1).astype(jnp.float32)
+    d1 = d_blk.reshape(n_e).astype(jnp.float32)
+    m1 = m_blk.reshape(n_e).astype(jnp.float32)
     a_i = acc.reshape(S_i, 1).astype(jnp.float32)
     a_j = accj.reshape(S_j, 1).astype(jnp.float32)
+    (p1, d1, m1, v, vj, a_i, a_j), vma = _vary_all(p1, d1, m1, v, vj, a_i,
+                                                   a_j)
 
     grid = (S_i // block_i, S_j // block_j, n_e)
-    scalar_spec = pl.BlockSpec((1, 1), lambda i, j, e: (e, 0))
     in_specs = [
-        scalar_spec,                                             # p̂
-        scalar_spec,                                             # δ
-        scalar_spec,                                             # non-Ē mask
+        _BLOCK_SCALARS,                                          # p̂
+        _BLOCK_SCALARS,                                          # δ
+        _BLOCK_SCALARS,                                          # non-Ē mask
         pl.BlockSpec((block_i, block_e), lambda i, j, e: (i, e)),  # V rows
         pl.BlockSpec((block_j, block_e), lambda i, j, e: (j, e)),  # V cols
         pl.BlockSpec((block_i, 1), lambda i, j, e: (i, 0)),      # A_i
         pl.BlockSpec((block_j, 1), lambda i, j, e: (j, 0)),      # A_j
     ]
     out_spec = pl.BlockSpec((block_i, block_j), lambda i, j, e: (i, j))
-    out_sds = jax.ShapeDtypeStruct((S_i, S_j), jnp.float32)
+    out_sds = jax.ShapeDtypeStruct((S_i, S_j), jnp.float32, vma=vma)
 
     kernel = functools.partial(_copyscore_fused_kernel, s=float(s),
                                n_false=float(n_false))
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs,
         out_specs=[out_spec] * 5, out_shape=[out_sds] * 5,
-        interpret=interpret,
-    )(p2, d2, m2, v, vj, a_i, a_j)
+        interpret=_interpret_params(interpret),
+    )(p1, d1, m1, v, vj, a_i, a_j)

@@ -10,6 +10,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.copyscore import zeros_varying_like
+
 
 # ---------------------------------------------------------------------------
 # copyscore
@@ -45,7 +47,7 @@ def copyscore_ref(v, p_blk, acc, *, s, n_false, block_e=512,
         f = jnp.log(1.0 - s + s * pr_src / pr_ind)
         return (c + f * count, n + count, err + d_k * count), None
 
-    zero = jnp.zeros((S_i, S_j), jnp.float32)
+    zero = zeros_varying_like((S_i, S_j), v, vj, acc, accj, p_blk, d_blk)
     (c, n, err), _ = jax.lax.scan(body, (zero, zero, zero),
                                   (jnp.moveaxis(vi_f, 1, 0),
                                    jnp.moveaxis(vj_f, 1, 0),
@@ -91,7 +93,8 @@ def copyscore_fused_ref(v, p_blk, acc, *, s, n_false, block_e=512,
         return (cf + f_fwd * count, cb + f_bwd * count, n + count,
                 n_out + m_k * count, err + d_k * count), None
 
-    zero = jnp.zeros((S_i, S_j), jnp.float32)
+    zero = zeros_varying_like((S_i, S_j), v, vj, acc, accj, p_blk, d_blk,
+                              m_blk)
     carry, _ = jax.lax.scan(body, (zero,) * 5,
                             (jnp.moveaxis(vi_f, 1, 0), jnp.moveaxis(vj_f, 1, 0),
                              p_blk.astype(jnp.float32), d_blk, m_blk))

@@ -405,13 +405,13 @@ def main():
     args = ap.parse_args()
     # platform/flag setup must precede the first JAX call (the task
     # functions import jax lazily, so this is early enough)
-    if args.platform or args.host_devices:
-        from repro.runtime.platform import (set_host_device_count,
-                                            set_platform)
-        if args.platform:
-            set_platform(args.platform)
-        if args.host_devices:
-            set_host_device_count(args.host_devices)
+    from repro.runtime.platform import (enable_compile_cache,
+                                        set_host_device_count, set_platform)
+    if args.platform:
+        set_platform(args.platform)
+    if args.host_devices:
+        set_host_device_count(args.host_devices)
+    enable_compile_cache()
     if args.task == "detect":
         serve_detect(args)
     else:

@@ -12,22 +12,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax ≥ 0.6 exposes shard_map at the top level (with check_vma)
-    from jax import shard_map as _shard_map
-    _SM_NOCHECK = {"check_vma": False}
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_NOCHECK = {"check_rep": False}
-
-
-def _mark_varying(tree, axis):
-    """pcast-to-varying where the API exists (jax ≥ 0.7); no-op before."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.tree.map(
-            lambda z: jax.lax.pcast(z, (axis,), to="varying"), tree)
-    return tree
 
 
 def pipeline_apply(stage_fn, stage_params, x_micro, mesh: Mesh, axis: str):
@@ -71,7 +57,6 @@ def pipeline_apply(stage_fn, stage_params, x_micro, mesh: Mesh, axis: str):
 
         init = (jnp.zeros(mb_shape, x_all.dtype),
                 jnp.zeros((n_micro,) + mb_shape, x_all.dtype))
-        init = _mark_varying(init, axis)
         (_, outputs), _ = jax.lax.scan(tick, init, jnp.arange(n_ticks))
         # every stage holds an `outputs` buffer; only the last stage's is
         # real — zero the rest and psum to replicate it everywhere
@@ -79,11 +64,11 @@ def pipeline_apply(stage_fn, stage_params, x_micro, mesh: Mesh, axis: str):
             jnp.where(stage_id == n_stages - 1, outputs, 0.0), axis)
         return outputs
 
-    fn = _shard_map(
+    fn = shard_map(
         local, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params,
                                is_leaf=lambda x: hasattr(x, "shape")), P()),
         out_specs=P(),
-        **_SM_NOCHECK,
+        check_vma=False,
     )
     return fn(stage_params, x_micro)

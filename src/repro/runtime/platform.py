@@ -1,7 +1,11 @@
 """Platform setup + pipeline autotuning for launch and benchmarks.
 
-Two concerns the tiled engine's async pipeline (DESIGN.md §11) pushes to
+Three concerns the tiled engine's async pipeline (DESIGN.md §11) pushes to
 process startup:
+
+* **Persistent compilation cache** — ``enable_compile_cache`` keeps
+  compiled programs across processes: in ``JAX_COMPILATION_CACHE_DIR``
+  when that is set, else at one fixed directory of the checkout.
 
 * **XLA platform/flag setup** — ``set_platform`` selects the backend and,
   on GPU, turns on the latency-hiding scheduler + async collectives so the
@@ -19,12 +23,33 @@ from __future__ import annotations
 
 import json
 import os
+from pathlib import Path
 from typing import Callable, Iterable, Optional
 
 import jax
 
 #: Default location of the per-backend autotune cache (relative to cwd).
 AUTOTUNE_DIR = ".autotune"
+
+#: Compilation cache used when ``JAX_COMPILATION_CACHE_DIR`` is unset:
+#: ``.jax_cache`` at the checkout root, found from this file and not from
+#: the working directory. The path is part of the cache key, so it must not
+#: move between runs.
+COMPILE_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is changed; otherwise the cache goes to ``COMPILE_CACHE_DIR``.
+    Call before the first compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def set_platform(platform: str = "cpu") -> None:
@@ -121,5 +146,6 @@ def autotune(
     return out
 
 
-__all__ = ["AUTOTUNE_DIR", "autotune", "load_autotune",
-           "set_host_device_count", "set_platform"]
+__all__ = ["AUTOTUNE_DIR", "COMPILE_CACHE_DIR", "autotune",
+           "enable_compile_cache", "load_autotune", "set_host_device_count",
+           "set_platform"]

@@ -12,13 +12,8 @@ SCRIPT = textwrap.dedent("""
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
-    try:
-        from jax import shard_map
-        _sm_nocheck = {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        _sm_nocheck = {"check_rep": False}
 
     results = {}
 
@@ -51,7 +46,7 @@ SCRIPT = textwrap.dedent("""
     fn = jax.jit(shard_map(local, mesh=mesh2,
                            in_specs=(P("data"), P("data")),
                            out_specs=(P(None), P("data")),
-                           **_sm_nocheck))
+                           check_vma=False))
     summed, err = fn(g, jnp.zeros_like(g))
     exact = g.sum(axis=0)
     rel = float(jnp.abs(summed[0] - exact).max() / jnp.abs(exact).max())
