@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import math
 import sys
 import time
 from pathlib import Path
@@ -44,6 +43,8 @@ ROWS_PER_REQUEST = 4
 N_REQUESTS = 16
 BATCH_REQUESTS = 8
 SLICE_SOURCES = 512
+FOUR_CHIP_DEVICES = 4         # the 1-D tile mesh of --chips 4
+FOUR_CHIP_MESH = (2, 2)       # its data x pod mesh
 
 
 class SmokeFailure(RuntimeError):
@@ -112,40 +113,38 @@ def check_same_decisions(name: str, got, want) -> None:
 
 
 def scan_has_pallas_kernel(engine) -> bool:
-    """Lower the engine's cached tile scan at the shapes of its last pass
-    and look for the Mosaic kernel call in the compiled program."""
+    """Compile the engine's cached tile scan (its mesh, scores and kernel
+    implementation) at a small fixed shape, two 256-row tiles over one
+    512-entry chunk, and look for the Mosaic kernel call in the program."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core.distributed import _sharded_tile_fn
 
-    st = engine.last_stats
-    T = st["tile"]
-    n_blocks = (math.isqrt(8 * st["tiles_total"] + 1) - 1) // 2
-    S_pad = n_blocks * T
+    tile, block, chunk = 256, 128, 512
     mesh = engine.mesh()
-    n_tiles = -(-st["tiles_kept"] // mesh.size) * mesh.size
-    block = 128 if T % 128 == 0 else T
-    G, w = st["chunk_group"], st["chunk_width"]
-    fn = _sharded_tile_fn(mesh, T, engine.cfg.s, engine.cfg.n,
+    fn = _sharded_tile_fn(mesh, tile, engine.cfg.s, engine.cfg.n,
                           engine.options.kernel_impl, block, block)
-    rep = NamedSharding(mesh, P())
 
-    def sds(shape, dtype, sharding=rep):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    def sds(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
 
-    lowered = fn.lower(sds((S_pad, G, w), jnp.int8), sds((S_pad,), jnp.float32),
-                       sds((G,), jnp.float32), sds((G,), jnp.float32),
-                       sds((G,), jnp.float32),
-                       sds((n_tiles, 2), jnp.int32,
-                           NamedSharding(mesh, P(mesh.axis_names[0]))))
+    lowered = fn.lower(sds((2 * tile, 1, chunk), jnp.int8),
+                       sds((2 * tile,), jnp.float32),
+                       sds((1,), jnp.float32), sds((1,), jnp.float32),
+                       sds((1,), jnp.float32),
+                       sds((mesh.size, 2), jnp.int32,
+                           P(mesh.axis_names[0])))
     return "tpu_custom_call" in lowered.compile().as_text()
 
 
-def one_chip(spec, impl: str = "pallas", slice_sources: int = SLICE_SOURCES,
-             expect_kernel: bool = True) -> None:
-    """The one-chip phases (module docstring); raises on any mismatch."""
+def one_chip(spec, impl: str = "pallas",
+             slice_sources: int = SLICE_SOURCES) -> None:
+    """The one-chip phases (module docstring); raises on any mismatch. The
+    compiled scan is checked for the Mosaic kernel when ``impl`` is
+    ``"pallas"``."""
     import numpy as np
 
     from repro.core import CopyConfig
@@ -231,7 +230,7 @@ def one_chip(spec, impl: str = "pallas", slice_sources: int = SLICE_SOURCES,
         f"{', '.join(f'{w:.3f}' for w in walls)} s per batch")
     log(f"pallas == ref decisions on 3 waves of {N_REQUESTS} requests")
 
-    if expect_kernel:
+    if impl == "pallas":
         if not scan_has_pallas_kernel(svc.engine):
             raise SmokeFailure("compiled tile scan has no tpu_custom_call")
         log("compiled tile scan contains tpu_custom_call")
@@ -259,8 +258,7 @@ def tile_grids(engine, ds, p):
     return result.copying, n_cnt, n_out
 
 
-def four_chips(spec, impl: str = "pallas", n_devices: int = 4,
-               mesh_shape: tuple = (2, 2)) -> None:
+def four_chips(spec, impl: str = "pallas") -> None:
     """The four-chip phases (module docstring); raises on any mismatch."""
     import numpy as np
 
@@ -275,10 +273,12 @@ def four_chips(spec, impl: str = "pallas", n_devices: int = 4,
     log(f"corpus {ds.n_sources} sources x {ds.n_items} items")
     warm, _ = make_requests(sc, BATCH_REQUESTS, seed=2)
     requests, _ = make_requests(sc, N_REQUESTS, seed=1)
+    rows, pods = FOUR_CHIP_MESH
     layouts = {"1 device": dict(devices=1),
-               f"{n_devices}-device 1-D mesh": dict(devices=n_devices),
-               f"{mesh_shape[0]}x{mesh_shape[1]} data x pod mesh":
-                   dict(mesh_shape=tuple(mesh_shape))}
+               f"{FOUR_CHIP_DEVICES}-device 1-D mesh":
+                   dict(devices=FOUR_CHIP_DEVICES),
+               f"{rows}x{pods} data x pod mesh":
+                   dict(mesh_shape=FOUR_CHIP_MESH)}
     base = None
     for name, layout in layouts.items():
         svc = DetectionService(ds, p, cfg, mode="bucketed", kernel_impl=impl,

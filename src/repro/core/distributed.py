@@ -33,8 +33,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.scoring import score_same
 from repro.core.types import CopyConfig
-from repro.kernels.copyscore import zeros_varying_like
 from repro.kernels.ops import copyscore_tile_fused
+from repro.kernels.vma import zeros_varying_like
 
 
 # ---------------------------------------------------------------------------

@@ -50,33 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _operand_vma(*operands) -> frozenset:
-    """Mesh axes any operand varies over (empty outside ``shard_map``).
-
-    Inside ``shard_map`` a value built from constants is replicated, while
-    a result computed from varying operands varies over their axes. Kernel
-    outputs and scan carries must carry that union, or the type check
-    rejects the mismatch.
-    """
-    return frozenset().union(*(jax.typeof(x).vma for x in operands))
-
-
-def _vary_all(*operands):
-    """Cast every operand to vary over the union of their mesh axes, so the
-    kernel body's elementwise ops (evaluated directly in interpret mode)
-    see operands of one type; returns the operands and that union."""
-    vma = _operand_vma(*operands)
-    return [jax.lax.pcast(x, tuple(vma - jax.typeof(x).vma), to="varying")
-            for x in operands], vma
-
-
-def zeros_varying_like(shape, *operands):
-    """f32 zeros that vary over every mesh axis ``operands`` vary over: the
-    start of an accumulator (or a skipped branch's result) that must type
-    like values computed from those operands."""
-    return jax.lax.pcast(jnp.zeros(shape, jnp.float32),
-                         tuple(_operand_vma(*operands)), to="varying")
+from repro.kernels.vma import vary_all
 
 
 def _interpret_params(interpret: bool):
@@ -195,7 +169,7 @@ def copyscore_pallas(
     scalars = [x.reshape(n_e).astype(jnp.float32) for x in scalars]
     a_i = acc.reshape(S_i, 1).astype(jnp.float32)
     a_j = accj.reshape(S_j, 1).astype(jnp.float32)
-    (v, vj, a_i, a_j, *scalars), vma = _vary_all(v, vj, a_i, a_j, *scalars)
+    (v, vj, a_i, a_j, *scalars), vma = vary_all(v, vj, a_i, a_j, *scalars)
 
     grid = (S_i // block_i, S_j // block_j, n_e)
     in_specs = [_BLOCK_SCALARS] * len(scalars) + [               # p̂[, δ]
@@ -302,8 +276,8 @@ def copyscore_fused_pallas(
     m1 = m_blk.reshape(n_e).astype(jnp.float32)
     a_i = acc.reshape(S_i, 1).astype(jnp.float32)
     a_j = accj.reshape(S_j, 1).astype(jnp.float32)
-    (p1, d1, m1, v, vj, a_i, a_j), vma = _vary_all(p1, d1, m1, v, vj, a_i,
-                                                   a_j)
+    (p1, d1, m1, v, vj, a_i, a_j), vma = vary_all(p1, d1, m1, v, vj, a_i,
+                                                  a_j)
 
     grid = (S_i // block_i, S_j // block_j, n_e)
     in_specs = [

@@ -10,7 +10,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.copyscore import zeros_varying_like
+from repro.kernels.vma import zeros_varying_like
 
 
 # ---------------------------------------------------------------------------

@@ -36,8 +36,7 @@ def _run(args, cwd, extra_env=None, timeout=600):
 
 def test_one_chip_phases_tiny_interpret():
     smoke = _load_smoke()
-    smoke.one_chip(SyntheticSpec(**TINY), impl="interpret", slice_sources=96,
-                   expect_kernel=False)
+    smoke.one_chip(SyntheticSpec(**TINY), impl="interpret", slice_sources=96)
 
 
 def test_four_chip_phases_tiny_on_virtual_devices():
