@@ -1,0 +1,6 @@
+"""Finalize outside the exact rescore (``engine.decide``), seconds per pass."""
+import program_trace
+
+
+def read(run):
+    return program_trace.span_per_pass(run, 'engine.decide')

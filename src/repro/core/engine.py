@@ -88,6 +88,7 @@ from repro.core.scoring import (
     posterior_independence_np,
 )
 from repro.core.types import ClaimsDataset, CopyConfig, DetectionResult
+from repro.utils import trace
 from repro.utils.counters import ComputeCounter
 
 MODES = ("pairwise", "exact", "bucketed", "bound", "bound+", "hybrid",
@@ -297,6 +298,7 @@ class DetectionEngine:
 
     # -- incremental tile-prune mask cache (DESIGN.md §11) ------------------
 
+    @trace.spanned("engine.mask_delta")
     def apply_mask_delta(self, delta):
         """Propagate a commit/retract ``MutationDelta`` into the mask cache.
 
@@ -315,6 +317,7 @@ class DetectionEngine:
         inner = cache.apply(delta)
         return None if inner is None else (cache, inner)
 
+    @trace.spanned("engine.mask_delta")
     def undo_mask_delta(self, token) -> None:
         """Reverse ``apply_mask_delta`` after the index store rolled back.
 
@@ -329,6 +332,7 @@ class DetectionEngine:
         cache.undo(inner)
         self._mask_cache = cache
 
+    @trace.spanned("engine.mask_delta")
     def rebase_mask_cache(self, delta) -> None:
         """Re-anchor a cache adopted DURING a transient commit onto the base.
 
@@ -357,6 +361,7 @@ class DetectionEngine:
 
     # -- dispatch -----------------------------------------------------------
 
+    @trace.spanned("engine.detect")
     def detect(
         self,
         ds: ClaimsDataset,
@@ -379,6 +384,7 @@ class DetectionEngine:
         Returns a ``DetectionResult`` over every ordered source pair;
         per-run diagnostics land in ``self.last_stats``.
         """
+        trace.annotate(mode=self.mode)
         opt = self.options
         if self.mode == "pairwise":
             return pairwise_detect(ds, p_claim, self.cfg)
@@ -619,7 +625,10 @@ class DetectionEngine:
         v = np.asarray(v_np, np.dtype(dtype))
         if self.options.mesh_shape is not None:
             return v
-        return jax.device_put(v, NamedSharding(self.mesh(), P()))
+        sharding = NamedSharding(self.mesh(), P())
+        trace.annotate(bytes=v.nbytes)
+        trace.count("engine.h2d_bytes", v.nbytes * len(sharding.device_set))
+        return jax.device_put(v, sharding)
 
     # scatter lives in shardplan (shared with OwnerPartial.to_grids); the
     # staticmethod survives for callers that patched/tuned it per engine
@@ -724,17 +733,19 @@ class DetectionEngine:
                              depth=self.options.prefetch_depth)
         try:
             for v_dev, p_g, d_g, o_g, coords_g in pf:
-                outs = self._tile_kernel(v_dev, acc_slab, p_g, coords_g, T,
-                                         d_g, o_g, block)
-                stacks = (list(outs) if stacks is None
-                          else [st + o for st, o in zip(stacks, outs)])
+                with trace.span("engine.scan.dispatch"):
+                    outs = self._tile_kernel(v_dev, acc_slab, p_g, coords_g,
+                                             T, d_g, o_g, block)
+                    stacks = (list(outs) if stacks is None
+                              else [st + o for st, o in zip(stacks, outs)])
         finally:
             pf.close()
             for key in self._pipe:
                 self._pipe[key] += getattr(pf, key)
         if stacks is not None:
-            stacks = [np.asarray(s, np.float32)[: len(coords_s)]
-                      for s in stacks]
+            with trace.span("engine.scan.collect"):
+                stacks = [np.asarray(s, np.float32)[: len(coords_s)]
+                          for s in stacks]
         return stacks, run
 
     def _detect_tiled(
@@ -747,6 +758,7 @@ class DetectionEngine:
         grids, chunk_tiles_run = self._run_tiled_scan(ctx)
         return self._tiled_finalize(ctx, grids, chunk_tiles_run)
 
+    @trace.spanned("engine.prologue")
     def _tiled_prologue(
         self,
         ds: ClaimsDataset,
@@ -783,12 +795,15 @@ class DetectionEngine:
         # tile-grid padding so chunks slice straight into pair tiles. The
         # byte budget caps the chunk width so even ONE shipped chunk
         # respects it (floored at 8 entries inside engine_chunks).
-        ech = engine_chunks(
-            base_idx, opt.n_buckets, row_capacity=S_pad,
-            max_width=opt.chunk_group_bytes // max(S_pad * itemsize, 1))
+        with trace.span("engine.chunk_gather"):
+            ech = engine_chunks(
+                base_idx, opt.n_buckets, row_capacity=S_pad,
+                max_width=opt.chunk_group_bytes // max(S_pad * itemsize, 1))
         K = ech.n_chunks
         b = ech.width
-        delta = self._bucket_deltas(ech.p_hat, ech.p_lo, ech.p_hi, ds.accuracy)
+        with trace.span("engine.bucket_deltas"):
+            delta = self._bucket_deltas(ech.p_hat, ech.p_lo, ech.p_hi,
+                                        ds.accuracy)
         # row-range sharded plane (DESIGN.md §10): the engine store is a
         # ShardedCorpusStore whenever the index's store was (gather_entries
         # preserves the plan). Sealing freezes it for the scan — optionally
@@ -810,52 +825,53 @@ class DetectionEngine:
         # tile then skips every chunk whose chunk_keep bit is off (its
         # contribution to all five channels would be zero). The keep matrix
         # is symmetric, so only unordered (r ≤ c) tiles are scheduled.
-        keep = np.zeros((n_blocks, n_blocks), bool)
-        chunk_keep = np.zeros((K, n_blocks, n_blocks), bool)
         base_store = base_idx.store
         cache = self._mask_cache if index is not None else None
         mask_source = "fresh"
-        if (cache is not None and cache.matches(base_store, T)
-                and cache.block_inc.shape == (n_blocks,
-                                              base_store.n_entries)):
-            # delta-maintained cache hit (DESIGN.md §11): derive each
-            # GATHERED chunk's mask by permuting cached base columns
-            # through the gather order — bit-equal to a fresh reduction
-            # of the gathered chunk, with zero full-chunk regathers
-            mask_source = "cache"
-            self._mask_cache_hits += 1
-            for k in range(K):
-                g_k = cache.chunk_mask(
-                    ech.order[k * b:(k + 1) * b]).astype(np.int32)
-                chunk_keep[k] = (g_k @ g_k.T) > 0
-                if k < ech.ebar_chunk:
-                    keep |= chunk_keep[k]
-        else:
-            # fresh full reduction (sharded stores reduce shard by shard —
-            # no host assembles the full chunk). When detecting against a
-            # persistent index, adopt the result as the new mask cache at
-            # zero extra reduction cost: scatter each gathered chunk's
-            # columns back to base entry order.
-            base_inc = None
-            base_mseq = -1
-            if index is not None:
-                base_inc = np.zeros((n_blocks, base_store.n_entries), bool)
-                base_mseq = getattr(base_store, "mseq", -1)
-            for k in range(K):
-                g_bool = tilecache.chunk_block_inc(ech.store, k, T, n_blocks)
+        with trace.span("engine.tile_masks"):
+            keep = np.zeros((n_blocks, n_blocks), bool)
+            chunk_keep = np.zeros((K, n_blocks, n_blocks), bool)
+            if (cache is not None and cache.matches(base_store, T)
+                    and cache.block_inc.shape == (n_blocks,
+                                                  base_store.n_entries)):
+                # delta-maintained cache hit (DESIGN.md §11): derive each
+                # GATHERED chunk's mask by permuting cached base columns
+                # through the gather order — bit-equal to a fresh reduction
+                # of the gathered chunk, with zero full-chunk regathers
+                mask_source = "cache"
+                self._mask_cache_hits += 1
+                for k in range(K):
+                    g_k = cache.chunk_mask(
+                        ech.order[k * b:(k + 1) * b]).astype(np.int32)
+                    chunk_keep[k] = (g_k @ g_k.T) > 0
+                    if k < ech.ebar_chunk:
+                        keep |= chunk_keep[k]
+            else:
+                # fresh full reduction (sharded stores reduce shard by shard —
+                # no host assembles the full chunk). When detecting against a
+                # persistent index, adopt the result as the new mask cache at
+                # zero extra reduction cost: scatter each gathered chunk's
+                # columns back to base entry order.
+                base_inc = None
+                base_mseq = -1
+                if index is not None:
+                    base_inc = np.zeros((n_blocks, base_store.n_entries), bool)
+                    base_mseq = getattr(base_store, "mseq", -1)
+                for k in range(K):
+                    g_bool = tilecache.chunk_block_inc(ech.store, k, T, n_blocks)
+                    if base_inc is not None:
+                        sel = ech.order[k * b: k * b + g_bool.shape[1]]
+                        live = sel >= 0
+                        if live.any():
+                            base_inc[:, sel[live]] = g_bool[:, live]
+                    g_k = g_bool.astype(np.int32)
+                    chunk_keep[k] = (g_k @ g_k.T) > 0
+                    if k < ech.ebar_chunk:
+                        keep |= chunk_keep[k]
                 if base_inc is not None:
-                    sel = ech.order[k * b: k * b + g_bool.shape[1]]
-                    live = sel >= 0
-                    if live.any():
-                        base_inc[:, sel[live]] = g_bool[:, live]
-                g_k = g_bool.astype(np.int32)
-                chunk_keep[k] = (g_k @ g_k.T) > 0
-                if k < ech.ebar_chunk:
-                    keep |= chunk_keep[k]
-            if base_inc is not None:
-                self._mask_cache = tilecache.BlockOrCache(
-                    base_store, T, base_mseq, base_inc)
-                self._mask_full_builds += 1
+                    self._mask_cache = tilecache.BlockOrCache(
+                        base_store, T, base_mseq, base_inc)
+                    self._mask_full_builds += 1
         coords = np.argwhere(np.triu(keep)).astype(np.int32)  # r ≤ c tiles
         tiles_total = n_blocks * (n_blocks + 1) // 2
         n_tiles = len(coords)
@@ -884,6 +900,7 @@ class DetectionEngine:
             # pass when the store is chunked — the full incidence is never
             # resident in a single allocation
             Gc = min(budget_chunks, max(1, K - 1))
+        trace.annotate(chunks=K, width=b, mask_source=mask_source)
         return TileScanContext(
             t0=t0, ds=ds, p_claim=p_claim, base_idx=base_idx, ech=ech,
             delta=delta, sharded=sharded, S=S, T=T, n_blocks=n_blocks,
@@ -892,6 +909,7 @@ class DetectionEngine:
             n_tiles=n_tiles, Gc=Gc, chunk_nbytes=chunk_nbytes,
             resident_nbytes=resident_nbytes, mask_source=mask_source)
 
+    @trace.spanned("engine.scan")
     def _run_tiled_scan(self, ctx: TileScanContext):
         """Step 3: the tile∘chunk scan — the four pair grids + run count."""
         opt = self.options
@@ -933,6 +951,7 @@ class DetectionEngine:
                 # per-chunk masks would allow — count what really runs
                 chunk_tiles_run += int(gmask.sum()) * len(ks)
                 groups.append((ks, gmask))
+            trace.annotate(groups=len(groups))
 
             def _stage(desc):
                 ks, gmask = desc
@@ -959,20 +978,26 @@ class DetectionEngine:
             pf = ChunkPrefetcher(groups, _stage, depth=opt.prefetch_depth)
             try:
                 for v_dev, p_g, d_g, o_g, coords_g in pf:
-                    outs = self._tile_kernel(v_dev, acc_pad, p_g, coords_g,
-                                             T, d_g, o_g, block)
-                    stacks = (list(outs) if stacks is None
-                              else [s + o for s, o in zip(stacks, outs)])
+                    # the kernel call; on an asynchronous backend only its
+                    # dispatch, the device work landing in the collect
+                    with trace.span("engine.scan.dispatch"):
+                        outs = self._tile_kernel(v_dev, acc_pad, p_g,
+                                                 coords_g, T, d_g, o_g, block)
+                        stacks = (list(outs) if stacks is None
+                                  else [s + o for s, o in zip(stacks, outs)])
             finally:
                 pf.close()
                 for key in self._pipe:
                     self._pipe[key] += getattr(pf, key)
             if stacks is None:
                 stacks = [jnp.zeros((n_tiles, T, T), jnp.float32)] * 5
-            self._scatter_tiles([c_same, n_cnt, n_out, err], coords, stacks,
-                                n_blocks, T)
+            # the device→host fetch of the tile stacks happens here
+            with trace.span("engine.scan.collect"):
+                self._scatter_tiles([c_same, n_cnt, n_out, err], coords,
+                                    stacks, n_blocks, T)
         return (c_same, n_cnt, n_out, err), chunk_tiles_run
 
+    @trace.spanned("engine.finalize")
     def _tiled_finalize(self, ctx: TileScanContext, grids,
                         chunk_tiles_run: int) -> DetectionResult:
         """Step 4: INDEX step 3 + error-bounded exact rescore + decide."""
@@ -987,45 +1012,53 @@ class DetectionEngine:
         chunk_nbytes, resident_nbytes = ctx.chunk_nbytes, ctx.resident_nbytes
         t0 = ctx.t0
         c_same, n_cnt, n_out, err = grids
-        c_same = c_same[:S, :S]
-        n_cnt = n_cnt[:S, :S]
-        err = err[:S, :S]
-        considered = n_out[:S, :S] > 0.5
-        np.fill_diagonal(considered, False)
+        # INDEX step 3 and the near-pair selection, then the rescore, then
+        # posteriors, decisions and counts: ``engine.decide`` is all but
+        # the rescore
+        with trace.span("engine.decide"):
+            c_same = c_same[:S, :S]
+            n_cnt = n_cnt[:S, :S]
+            err = err[:S, :S]
+            considered = n_out[:S, :S] > 0.5
+            np.fill_diagonal(considered, False)
 
-        # ---- INDEX step 3 + error-bounded exact rescore -------------------
-        c_fwd = np.where(considered,
-                         c_same + (base_idx.l_counts - n_cnt) * cfg.ln_1ms,
-                         0.0).astype(np.float32)
-        np.fill_diagonal(c_fwd, 0.0)
+            # ---- INDEX step 3 + error-bounded exact rescore ---------------
+            c_fwd = np.where(
+                considered, c_same + (base_idx.l_counts - n_cnt) * cfg.ln_1ms,
+                0.0).astype(np.float32)
+            np.fill_diagonal(c_fwd, 0.0)
 
-        # a pair's decision can only differ from the exact INDEX if the
-        # accumulated p̂ error reaches its decision margin — rescore exactly
-        # every such pair (err bounds |Δ C→|; |Δz| ≤ max of both directions)
-        z = np.log(cfg.alpha / cfg.beta) + np.logaddexp(c_fwd, c_fwd.T)
-        near = considered & (np.abs(z) <
-                             opt.rescore_margin + np.maximum(err, err.T))
-        near &= np.triu(np.ones_like(near), 1).astype(bool)
-        pi, pj = np.nonzero(near)
+            # a pair's decision can only differ from the exact INDEX if the
+            # accumulated p̂ error reaches its decision margin — rescore
+            # exactly every such pair (err bounds |Δ C→|; |Δz| ≤ max of both
+            # directions)
+            z = np.log(cfg.alpha / cfg.beta) + np.logaddexp(c_fwd, c_fwd.T)
+            near = considered & (np.abs(z) <
+                                 opt.rescore_margin + np.maximum(err, err.T))
+            near &= np.triu(np.ones_like(near), 1).astype(bool)
+            pi, pj = np.nonzero(near)
         n_rescored = rescore_pairs_exact(ds, p_claim, cfg, pi, pj, c_fwd)
 
-        pr_ind = posterior_independence_np(c_fwd, c_fwd.T, cfg)
-        copying = decide_copying_np(c_fwd, c_fwd.T, cfg) & considered
-        pr_ind = np.where(considered, pr_ind, 1.0).astype(np.float32)
-        np.fill_diagonal(pr_ind, 1.0)
-        np.fill_diagonal(copying, False)
-        self._last_considered = considered
+        with trace.span("engine.decide"):
+            pr_ind = posterior_independence_np(c_fwd, c_fwd.T, cfg)
+            copying = decide_copying_np(c_fwd, c_fwd.T, cfg) & considered
+            pr_ind = np.where(considered, pr_ind, 1.0).astype(np.float32)
+            np.fill_diagonal(pr_ind, 1.0)
+            np.fill_diagonal(copying, False)
+            self._last_considered = considered
 
-        # semantic (paper-metric) accounting, identical to the exact INDEX
-        iu = np.triu_indices(S, 1)
-        values_examined = int(n_cnt[iu][considered[iu]].sum())
-        n_pairs = int(considered[iu].sum())
-        counter = ComputeCounter(
-            pairs_considered=n_pairs,
-            shared_values_examined=values_examined,
-            score_computations=2 * values_examined + 2 * n_pairs + 2 * n_rescored,
-            index_entries=ech.n_live,
-        )
+            # semantic (paper-metric) accounting, identical to the exact
+            # INDEX
+            iu = np.triu_indices(S, 1)
+            values_examined = int(n_cnt[iu][considered[iu]].sum())
+            n_pairs = int(considered[iu].sum())
+            counter = ComputeCounter(
+                pairs_considered=n_pairs,
+                shared_values_examined=values_examined,
+                score_computations=(2 * values_examined + 2 * n_pairs
+                                    + 2 * n_rescored),
+                index_entries=ech.n_live,
+            )
         self.last_stats = {
             "tile": T,
             "tiles_total": tiles_total,        # unordered (r ≤ c) tiles

@@ -44,6 +44,7 @@ from repro.core.scoring import (
     score_same_np,
 )
 from repro.core.types import ClaimsDataset, CopyConfig, DetectionResult
+from repro.utils import trace
 from repro.utils.counters import ComputeCounter
 
 
@@ -70,6 +71,7 @@ class IncrementalState:
                                   # (0 where a round has rescored exactly)
 
 
+@trace.spanned("engine.rescore")
 def rescore_pairs_exact(
     ds: ClaimsDataset,
     p_claim: np.ndarray,
@@ -96,6 +98,7 @@ def rescore_pairs_exact(
 
     Returns the number of pairs rescored (0 for an empty list).
     """
+    trace.annotate(pairs=len(pi))
     if len(pi) == 0:
         return 0
     both = pair_scores_subset(ds, p_claim, cfg, np.concatenate([pi, pj]),

@@ -44,6 +44,7 @@ from repro.core.store import (
     align_chunk,
 )
 from repro.core.types import CLAIM_KEY_BASE, ClaimsDataset, CopyConfig, claim_value_keys
+from repro.utils import trace
 
 
 @dataclass
@@ -289,6 +290,7 @@ def _shared_item_counts(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     return (a.astype(np.float32) @ b.astype(np.float32).T).astype(np.int32)
 
 
+@trace.spanned("index.build")
 def build_index(
     ds: ClaimsDataset,
     p_claim: np.ndarray,
@@ -383,6 +385,7 @@ def build_index(
     ebar_start = _ebar_boundary(entry_score, cfg.theta_ind)
 
     l_counts = _shared_item_counts(prov, prov)
+    trace.annotate(entries=store.n_entries)
 
     return InvertedIndex(
         store=store,

@@ -21,6 +21,11 @@ thread; ``stage_wait_s`` then equals ``staging_s`` by construction, which
 is what makes "prefetch hides staging" a measurable claim
 (``stage_wait_s`` with prefetch < ``staging_s`` without).
 
+Each staging is a span ``engine.scan.stage`` (on the stage thread, its
+parent the span open where the prefetcher was made) and each consumer
+wait a span ``engine.scan.wait`` (``repro.utils.trace``); the telemetry
+above sums the same clock reads.
+
 A raising stage function surfaces as a typed ``PipelineStageError`` on the
 consumer side (original exception chained); ``close`` always reaps the
 thread and drains staged payloads so no device buffers are stranded.
@@ -31,6 +36,8 @@ import queue
 import threading
 import time
 from typing import Callable, Iterable
+
+from repro.utils import trace
 
 #: Sentinel kinds flowing through the queue alongside staged payloads.
 _ITEM, _DONE, _ERROR = "item", "done", "error"
@@ -56,6 +63,7 @@ class ChunkPrefetcher:
         self.compute_wait_s = 0.0
         self.staging_s = 0.0
         self._stage_fn = stage_fn
+        self._parent = trace.current()
         self._depth = max(int(depth), 0)
         self._stop = False
         self.thread = None
@@ -84,12 +92,12 @@ class ChunkPrefetcher:
             for d in self._descs:
                 if self._stop:
                     return
-                t0 = time.perf_counter()
-                staged = self._stage_fn(d)
-                self.staging_s += time.perf_counter() - t0
-                t1 = time.perf_counter()
+                with trace.span("engine.scan.stage",
+                                parent=self._parent) as sp:
+                    staged = self._stage_fn(d)
+                self.staging_s += sp.seconds
                 ok = self._put((_ITEM, staged))
-                self.compute_wait_s += time.perf_counter() - t1
+                self.compute_wait_s += time.perf_counter() - sp.t1
                 if not ok:
                     return
             self._put((_DONE, None))
@@ -106,28 +114,28 @@ class ChunkPrefetcher:
         """Next staged payload; blocks until staged (timed as stall)."""
         if self._depth == 0:
             d = next(self._it)           # StopIteration ends the loop
-            t0 = time.perf_counter()
             try:
-                staged = self._stage_fn(d)
+                with trace.span("engine.scan.stage",
+                                parent=self._parent) as sp:
+                    staged = self._stage_fn(d)
             except StopIteration:
                 raise
             except BaseException as exc:
                 raise PipelineStageError(
                     f"chunk staging failed: {exc!r}") from exc
-            dt = time.perf_counter() - t0
-            self.staging_s += dt
-            self.stage_wait_s += dt      # consumer waited the full time
+            self.staging_s += sp.seconds
+            self.stage_wait_s += sp.seconds   # consumer waited the full time
             return staged
-        t0 = time.perf_counter()
-        while True:
-            try:
-                kind, payload = self.q.get(timeout=0.5)
-                break
-            except queue.Empty:
-                if not self.thread.is_alive():
-                    raise PipelineStageError(
-                        "prefetch stage thread died without a result")
-        self.stage_wait_s += time.perf_counter() - t0
+        with trace.span("engine.scan.wait") as sp:
+            while True:
+                try:
+                    kind, payload = self.q.get(timeout=0.5)
+                    break
+                except queue.Empty:
+                    if not self.thread.is_alive():
+                        raise PipelineStageError(
+                            "prefetch stage thread died without a result")
+        self.stage_wait_s += sp.seconds
         if kind == _DONE:
             raise StopIteration
         if kind == _ERROR:

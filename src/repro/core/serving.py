@@ -106,6 +106,7 @@ from repro.core.wal import (
     write_manifest,
     write_snapshot,
 )
+from repro.utils import trace
 
 #: Engine modes that consume a prebuilt InvertedIndex — for these the service
 #: maintains ONE committed index across batches (per-batch transient commits
@@ -242,6 +243,7 @@ class ResidentCorpus:
         of the corpus in memory — not a second one next to the caller's."""
         return self._full.row_view(self.n_corpus)
 
+    @trace.spanned("service.stage_rows")
     def stage(self, requests: Sequence[DetectRequest]
               ) -> tuple[ClaimsDataset, np.ndarray, int]:
         """Write the batch's query rows into the slack; return the union view.
@@ -265,6 +267,7 @@ class ResidentCorpus:
             self.p_claim[rows] = r.p_claim
             written += r.values.nbytes + r.accuracy.nbytes + r.p_claim.nbytes
             off += r.n_rows
+        trace.annotate(bytes=written)
         return self._full.row_view(off), self.p_claim[:off], written
 
     # -- permanent commits (corpus mutation, DESIGN.md §7) -------------------
@@ -377,6 +380,7 @@ class ResidentCorpus:
         return self.n_corpus
 
 
+@trace.spanned("service.batch")
 def serve_batch(
     base: ClaimsDataset,
     base_p: np.ndarray,
@@ -420,6 +424,8 @@ def serve_batch(
                 f"request {r.rid}: {r.values.shape[1]} items, corpus has {D}")
     S0 = base.n_sources
     n_rows = sum(r.n_rows for r in requests)
+    trace.annotate(rids=[r.rid for r in requests], requests=len(requests),
+                   rows=n_rows)
     if resident is None:
         resident = ResidentCorpus(base, base_p, max_query_rows=n_rows)
     elif resident.n_corpus != S0 or resident.n_items != D:
@@ -432,9 +438,10 @@ def serve_batch(
     union, p, copied = resident.stage(requests)
 
     if index is not None and engine.mode in INDEXED_MODES:
-        index.store.ensure_row_capacity(union.n_sources)
-        info = commit_rows(index, union, p, engine.cfg,
-                           union.n_sources - S0, compact=False)
+        with trace.span("index.commit", rows=n_rows):
+            index.store.ensure_row_capacity(union.n_sources)
+            info = commit_rows(index, union, p, engine.cfg,
+                               union.n_sources - S0, compact=False)
         # carry the transient commit's delta into the engine's block-OR
         # mask cache so the batch detect updates O(touched) cells instead
         # of regathering all K chunk reductions (DESIGN.md §11)
@@ -444,7 +451,8 @@ def serve_batch(
         finally:
             # bit-exact unwind — a mid-batch engine failure must never leave
             # the batch's transient rows/deltas in the committed index
-            rollback_commit(index, info)
+            with trace.span("index.rollback", rows=n_rows):
+                rollback_commit(index, info)
             if token is not None:
                 engine.undo_mask_delta(token)
             else:
@@ -457,20 +465,21 @@ def serve_batch(
 
     out = []
     off = S0
-    for r in requests:
-        rows = slice(off, off + r.n_rows)
-        out.append(DetectResponse(
-            rid=r.rid,
-            copying=res.copying[rows, :S0].copy(),
-            pr_independent=res.pr_independent[rows, :S0].copy(),
-            c_fwd=res.c_fwd[rows, :S0].copy(),
-            intra_copying=res.copying[rows, rows].copy(),
-            batch_requests=len(requests),
-            batch_rows=n_rows,
-            engine_wall_s=res.wall_time_s,
-            host_copy_bytes=copied,
-        ))
-        off += r.n_rows
+    with trace.span("service.respond"):
+        for r in requests:
+            rows = slice(off, off + r.n_rows)
+            out.append(DetectResponse(
+                rid=r.rid,
+                copying=res.copying[rows, :S0].copy(),
+                pr_independent=res.pr_independent[rows, :S0].copy(),
+                c_fwd=res.c_fwd[rows, :S0].copy(),
+                intra_copying=res.copying[rows, rows].copy(),
+                batch_requests=len(requests),
+                batch_rows=n_rows,
+                engine_wall_s=res.wall_time_s,
+                host_copy_bytes=copied,
+            ))
+            off += r.n_rows
     return out
 
 

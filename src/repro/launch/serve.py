@@ -50,6 +50,10 @@
       receipt (snapshot epoch, replayed commits, discarded torn-tail bytes)
       is printed. With --replicas each replica persists under its own
       replica-<i>/ subdirectory.
+
+      At exit it prints the per-span totals of ``repro.utils.trace``: the
+      host time of every named step of the passes, and the bytes shipped to
+      the device (OPERATIONS.md, "Reading the spans").
 """
 from __future__ import annotations
 
@@ -319,6 +323,10 @@ def serve_detect(args):
         if args.replicas > 1:
             print(f"[serve] breaker: trips={st.breaker_trips} "
                   f"open_now={st.breaker_open}")
+
+    from repro.utils import trace
+    print("[serve] host spans and counters of this run:")
+    print(trace.table(trace.records()))
 
 
 def main():

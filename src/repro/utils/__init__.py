@@ -1,4 +1,3 @@
-from repro.utils.timing import Timer, timed
 from repro.utils.counters import ComputeCounter
 
-__all__ = ["Timer", "timed", "ComputeCounter"]
+__all__ = ["ComputeCounter"]
