@@ -13,7 +13,7 @@ Public API:
   sample_by_item, sample_by_cell, scale_sample  — sampling (§VI)
   fagin_input                                   — NRA baseline (Table X)
   DetectRequest, DetectionService, serve_batch  — batched serving (DESIGN §5)
-  CorpusStore, engine_chunks, ResidentCorpus    — chunked incidence store +
+  CorpusStore, engine_order, ResidentCorpus     — chunked incidence store +
                                                   resident serving buffers
                                                   (DESIGN §6)
   ShardPlan, ShardedCorpusStore, shard_store    — row-range-sharded corpus
@@ -51,7 +51,7 @@ from repro.core.index import (
     bucketize,
     commit_rows,
     compact_index,
-    engine_chunks,
+    engine_order,
     retract_rows,
     rollback_commit,
 )
@@ -122,7 +122,7 @@ __all__ = [
     "ServiceStopped",
     "DurabilityOptions", "CommitLog", "CommitRecord", "RestoreInfo",
     "NoValidSnapshotError", "ReplayDivergenceError", "RetractRecord",
-    "pairwise_detect", "build_index", "bucketize", "engine_chunks",
+    "pairwise_detect", "build_index", "bucketize", "engine_order",
     "commit_rows", "rollback_commit", "compact_index", "CommitInfo",
     "retract_rows", "RetractInfo",
     "index_detect_exact", "bucketed_index_detect",

@@ -144,7 +144,7 @@ def pad_buckets(b: BucketedIndex, dtype=None) -> PaddedBuckets:
     NOTE: this materializes the full (K, S, w) bucket tensor — it remains
     only as the single-device oracle / legacy-baseline form. Production
     paths stream chunks from the ``CorpusStore`` instead (the engine via
-    ``engine_chunks``, BOUND via ``_bound_stream``)."""
+    ``engine_order``, BOUND via ``_bound_stream``)."""
     if dtype is None:
         dtype = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
     idx = b.index

@@ -7,7 +7,7 @@ the sharded, pair-tiled dataflow of DESIGN.md §3:
 
   1. build the inverted index (§III — streamed into the chunked
      ``CorpusStore``, never a dense (S, E) array) and re-chunk it p-sorted
-     on each side of the Ē boundary (``engine_chunks`` — the accumulation
+     on each side of the Ē boundary (``engine_order`` — the accumulation
      is order-insensitive, so p-homogeneous chunks shrink the p̂ error;
      chunks double as the kernel's entry blocks);
   2. cut the S×S pair space into T×T tiles and prune, up front, every tile
@@ -19,10 +19,11 @@ the sharded, pair-tiled dataflow of DESIGN.md §3:
      the triangular schedule halves the tiles scheduled. The OR-reduction
      is kept per chunk, so tile pruning composes with chunk pruning
      (DESIGN.md §6);
-  3. stream chunk GROUPS (default one chunk per device pass — the peak
-     resident incidence is a single chunk; an optional byte budget groups
-     chunks for dispatch-bound meshes) over a 1-D device
-     mesh (shard_map); each device scans its surviving
+  3. stream chunk GROUPS (default one chunk per device pass; an optional
+     byte budget groups chunks for dispatch-bound meshes) over a 1-D
+     device mesh (shard_map) — each group's slab gathered on the device
+     from the base incidence shipped once per pass (``devchunks``), or on
+     the host for a row-sharded store; each device scans its surviving
      tiles, slicing the int8 chunk slab and feeding the fused
      dual-direction copyscore kernel one unordered tile at a time — one
      count matmul per entry block emits C→, C←, the shared count, the
@@ -59,7 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import tilecache
+from repro.core import devchunks, tilecache
 from repro.core.bound import bound_detect
 from repro.core.bucketed import index_detect_exact
 from repro.core.distributed import sharded_tile_scores, sharded_tile_scores_2d
@@ -69,7 +70,7 @@ from repro.core.incremental import (
     make_incremental_state,
     rescore_pairs_exact,
 )
-from repro.core.index import InvertedIndex, build_index, engine_chunks
+from repro.core.index import InvertedIndex, build_index, engine_order
 from repro.core.sampling import sample_by_cell, sample_by_item, scale_sample
 from repro.core.shardplan import (
     OwnerPartial,
@@ -88,6 +89,7 @@ from repro.core.scoring import (
     posterior_independence_np,
 )
 from repro.core.types import ClaimsDataset, CopyConfig, DetectionResult
+from repro.runtime.platform import keep_host_arrays_on_heap
 from repro.utils import trace
 from repro.utils.counters import ComputeCounter
 
@@ -216,7 +218,7 @@ class TileScanContext:
     ds: ClaimsDataset
     p_claim: np.ndarray
     base_idx: InvertedIndex
-    ech: object                    # EngineChunks — p-ordered scan store
+    ech: object                    # EngineChunks — p-ordered chunking
     delta: np.ndarray              # per-chunk p̂-error bound δ_k
     sharded: bool
     S: int
@@ -234,6 +236,9 @@ class TileScanContext:
     chunk_nbytes: int
     resident_nbytes: int
     mask_source: str
+    gather: str                    # "device" | "host" — where slabs are gathered
+    resident: object = None        # device copy of the base incidence (device
+                                   # gather), dropped when the scan ends
     items: Optional[np.ndarray] = None   # sampled/sample_verify item subset
 
 
@@ -250,6 +255,7 @@ class DetectionEngine:
         self.cfg = cfg
         self.mode = mode
         self.options = EngineOptions(**options)
+        keep_host_arrays_on_heap()
         self.last_stats: dict = {}
         self._mesh: Optional[Mesh] = None
         self._mesh2: Optional[Mesh] = None
@@ -630,6 +636,20 @@ class DetectionEngine:
         trace.count("engine.h2d_bytes", v.nbytes * len(sharding.device_set))
         return jax.device_put(v, sharding)
 
+    def _gathers_on_device(self, store, S_pad: int) -> bool:
+        """Whether the scan gathers its slabs on the device (DESIGN.md §6):
+        on the 1-D mesh over an unsharded store, wherever the resident base
+        incidence and the slabs in flight fit every device's memory (a
+        store split over row shards never holds every row on one device).
+        """
+        opt = self.options
+        if opt.mesh_shape is not None or isinstance(store,
+                                                    ShardedCorpusStore):
+            return False
+        need = (devchunks.device_nbytes(store, S_pad)
+                + (opt.prefetch_depth + 2) * opt.chunk_group_bytes)
+        return devchunks.fits(self.mesh().devices.flat, need)
+
     # scatter lives in shardplan (shared with OwnerPartial.to_grids); the
     # staticmethod survives for callers that patched/tuned it per engine
     _scatter_tiles = staticmethod(scatter_tile_stacks)
@@ -791,14 +811,30 @@ class DetectionEngine:
         itemsize = np.dtype(np.int8 if dtype == jnp.int8 else
                             np.float32 if dtype == jnp.float32
                             else np.float16).itemsize
-        # p-ordered, region-padded, uniform-width chunk store; rows carry the
-        # tile-grid padding so chunks slice straight into pair tiles. The
-        # byte budget caps the chunk width so even ONE shipped chunk
-        # respects it (floored at 8 entries inside engine_chunks).
-        with trace.span("engine.chunk_gather"):
-            ech = engine_chunks(
-                base_idx, opt.n_buckets, row_capacity=S_pad,
+        # p-ordered, region-padded, uniform-width chunks. The byte budget
+        # caps the chunk width so even ONE shipped chunk respects it
+        # (floored at 8 entries inside engine_order). On the unsharded 1-D
+        # mesh the base incidence ships as it sits and every scan group
+        # gathers its slab on the device (DESIGN.md §6); a row-sharded
+        # store and the 2-D mesh scan host chunks, gathered here with rows
+        # padded to the tile grid so they slice straight into pair tiles.
+        base_store = base_idx.store
+        sharded = isinstance(base_store, ShardedCorpusStore)
+        resident = None
+        with trace.span("engine.chunk_gather") as sp:
+            ech = engine_order(
+                base_idx, opt.n_buckets,
                 max_width=opt.chunk_group_bytes // max(S_pad * itemsize, 1))
+            if ech.n_chunks and self._gathers_on_device(base_store, S_pad):
+                sharding = NamedSharding(self.mesh(), P())
+                resident, nbytes = devchunks.upload(base_store, S_pad,
+                                                    sharding)
+                trace.count("engine.h2d_bytes",
+                            nbytes * len(sharding.device_set))
+            else:
+                ech.gather(base_idx, row_capacity=S_pad)
+            gather = "host" if resident is None else "device"
+            sp.set(gather=gather)
         K = ech.n_chunks
         b = ech.width
         with trace.span("engine.bucket_deltas"):
@@ -809,7 +845,6 @@ class DetectionEngine:
         # preserves the plan). Sealing freezes it for the scan — optionally
         # bitpacked to 1 bit/entry and/or under a per-shard LRU byte cap
         # with cold blocks spilled to checksummed frames.
-        sharded = isinstance(ech.store, ShardedCorpusStore)
         if sharded and (opt.shard_pack or opt.shard_spill_bytes is not None):
             ech.store.seal(pack=opt.shard_pack,
                            spill_dir=opt.shard_spill_dir,
@@ -825,7 +860,6 @@ class DetectionEngine:
         # tile then skips every chunk whose chunk_keep bit is off (its
         # contribution to all five channels would be zero). The keep matrix
         # is symmetric, so only unordered (r ≤ c) tiles are scheduled.
-        base_store = base_idx.store
         cache = self._mask_cache if index is not None else None
         mask_source = "fresh"
         with trace.span("engine.tile_masks"):
@@ -834,12 +868,23 @@ class DetectionEngine:
             if (cache is not None and cache.matches(base_store, T)
                     and cache.block_inc.shape == (n_blocks,
                                                   base_store.n_entries)):
-                # delta-maintained cache hit (DESIGN.md §11): derive each
-                # GATHERED chunk's mask by permuting cached base columns
-                # through the gather order — bit-equal to a fresh reduction
-                # of the gathered chunk, with zero full-chunk regathers
+                # delta-maintained cache hit (DESIGN.md §11)
                 mask_source = "cache"
                 self._mask_cache_hits += 1
+            elif ech.store is None:
+                # no host chunks (device gather): reduce the BASE chunks;
+                # adopted as the mask cache when detecting against a
+                # persistent index
+                cache = tilecache.BlockOrCache.build(base_store, T)
+                if index is not None:
+                    self._mask_cache = cache
+                    self._mask_full_builds += 1
+            else:
+                cache = None
+            if cache is not None:
+                # each GATHERED chunk's mask permutes base columns through
+                # the gather order — bit-equal to a fresh reduction of the
+                # gathered chunk
                 for k in range(K):
                     g_k = cache.chunk_mask(
                         ech.order[k * b:(k + 1) * b]).astype(np.int32)
@@ -847,11 +892,12 @@ class DetectionEngine:
                     if k < ech.ebar_chunk:
                         keep |= chunk_keep[k]
             else:
-                # fresh full reduction (sharded stores reduce shard by shard —
-                # no host assembles the full chunk). When detecting against a
-                # persistent index, adopt the result as the new mask cache at
-                # zero extra reduction cost: scatter each gathered chunk's
-                # columns back to base entry order.
+                # fresh full reduction of the host chunks (sharded stores
+                # reduce shard by shard — no host assembles the full chunk).
+                # When detecting against a persistent index, adopt the
+                # result as the new mask cache at zero extra reduction
+                # cost: scatter each gathered chunk's columns back to base
+                # entry order.
                 base_inc = None
                 base_mseq = -1
                 if index is not None:
@@ -907,7 +953,8 @@ class DetectionEngine:
             S_pad=S_pad, acc_pad=acc_pad, block=block, dtype=dtype,
             chunk_keep=chunk_keep, coords=coords, tiles_total=tiles_total,
             n_tiles=n_tiles, Gc=Gc, chunk_nbytes=chunk_nbytes,
-            resident_nbytes=resident_nbytes, mask_source=mask_source)
+            resident_nbytes=resident_nbytes, mask_source=mask_source,
+            gather=gather, resident=resident)
 
     @trace.spanned("engine.scan")
     def _run_tiled_scan(self, ctx: TileScanContext):
@@ -961,18 +1008,25 @@ class DetectionEngine:
                 p_g = np.full(Gc, 0.5, np.float32)
                 d_g = np.zeros(Gc, np.float32)
                 o_g = np.zeros(Gc, np.float32)
+                p_g[: len(ks)] = ech.p_hat[ks]
+                d_g[: len(ks)] = delta[ks]
+                o_g[: len(ks)] = ech.nout[ks]
+                if ctx.resident is not None:
+                    # the group's columns, -1 (a zero column) past the end
+                    cols = np.full(Gc * b, -1, np.int64)
+                    seg = ech.order[ks[0] * b:(ks[-1] + 1) * b]
+                    cols[: len(seg)] = seg
+                    trace.count("engine.device_gathered_chunks", len(ks))
+                    return (devchunks.gather(ctx.resident, cols, Gc, dtype),
+                            p_g, d_g, o_g, coords_g)
                 if Gc == 1:
                     # store chunks are already contiguous (S_pad, b) — ship
                     # a zero-copy view instead of re-copying the incidence
                     v_np = ech.store.chunks[ks[0]].reshape(S_pad, 1, b)
                 else:
                     v_np = np.zeros((S_pad, Gc, b), np.int8)
-                for i, k in enumerate(ks):
-                    if Gc > 1:
+                    for i, k in enumerate(ks):
                         v_np[:, i, :] = ech.store.chunks[k]
-                    p_g[i] = ech.p_hat[k]
-                    d_g[i] = delta[k]
-                    o_g[i] = ech.nout[k]
                 return self._stage_v(v_np, dtype), p_g, d_g, o_g, coords_g
 
             pf = ChunkPrefetcher(groups, _stage, depth=opt.prefetch_depth)
@@ -995,6 +1049,11 @@ class DetectionEngine:
             with trace.span("engine.scan.collect"):
                 self._scatter_tiles([c_same, n_cnt, n_out, err], coords,
                                     stacks, n_blocks, T)
+        if ctx.resident is not None:
+            # free the device copy; wait for its upload first, which may
+            # still read host chunks that the caller changes after the pass
+            ctx.resident.block_until_ready()
+            ctx.resident = None
         return (c_same, n_cnt, n_out, err), chunk_tiles_run
 
     @trace.spanned("engine.finalize")
@@ -1078,6 +1137,7 @@ class DetectionEngine:
             "chunk_tiles_total": K * n_tiles,
             "chunk_tiles_run": chunk_tiles_run,
             "peak_group_bytes": int(Gc * chunk_nbytes),
+            "gather": ctx.gather,
             "resident_chunk_bytes": int(resident_nbytes),
             # async staging pipeline (DESIGN.md §11)
             "prefetch_depth": int(opt.prefetch_depth),

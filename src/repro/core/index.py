@@ -839,7 +839,7 @@ def _segment_p_stats(entry_p: np.ndarray, live: np.ndarray,
     """Per-segment (p̂, p_lo, p_hi) over the LIVE columns of each
     ``[bounds[k], bounds[k+1])`` range — geometric-mean representative and
     true extremes, 0.5 fallbacks for all-padding segments. The one
-    implementation behind both ``bucketize`` and ``engine_chunks``, so the
+    implementation behind both ``bucketize`` and ``engine_order``, so the
     p̂ feeding BOUND's and the engine's shared δ error channel can never
     drift apart.
     """
@@ -864,7 +864,8 @@ def canonicalized(index: InvertedIndex, cfg: CopyConfig) -> InvertedIndex:
 
     Returns ``index`` unchanged when it is already canonical. Otherwise
     gathers the live entries in decreasing-score order into a fresh store —
-    a detection-time copy exactly like ``engine_chunks``' per-call gather,
+    a detection-time copy exactly like the engine's host gather
+    (``EngineChunks.gather``),
     NOT a mutation of the committed index. BOUND's scan uses this so its
     bucket geometry (and with it the Eq. 10 ``h`` overlap estimate, which is
     scan-order-dependent by design) is identical whether the index was
@@ -962,7 +963,7 @@ def bucketize_engine(
     """p-homogeneous bucketization (legacy full-reorder form).
 
     Kept for the kernel microbenchmark's legacy baseline; the production
-    engine uses ``engine_chunks`` (below), which produces the same p-sorted
+    engine uses ``engine_order`` (below), which produces the same p-sorted
     regions as a uniform-width chunk store without variable-width buckets.
 
     Returns (bucketed, p_lo, p_hi): a BucketedIndex over a reordered copy of
@@ -1020,40 +1021,45 @@ class EngineChunks:
     Entries are re-sorted by truth probability within the non-Ē prefix and
     within Ē (the tiled accumulation is order-insensitive; only the Ē
     boundary must stay exact), each region is zero-padded to a chunk
-    multiple, and the result is a uniform-width ``CorpusStore`` whose chunks
-    double as the kernel's entry blocks: each chunk k carries one
-    representative p̂_k, its true p extremes (for the rescore bound δ_k),
-    and a non-Ē flag. Row capacity is padded to the engine's tile grid so
-    chunk arrays slice straight into pair tiles.
+    multiple, and the chunks double as the kernel's entry blocks: each
+    chunk k carries one representative p̂_k, its true p extremes (for the
+    rescore bound δ_k), and a non-Ē flag. ``store`` is the gathered
+    uniform-width ``CorpusStore`` (rows padded to the engine's tile grid,
+    so chunk arrays slice straight into pair tiles); it is None until
+    ``gather`` builds it, and the engine builds it only where it scans
+    host chunks (DESIGN.md §6).
     """
 
-    store: CorpusStore        # p-ordered regions, uniform chunk width
     p_hat: np.ndarray         # (K,) float32 — representative p̂ per chunk
     p_lo: np.ndarray          # (K,) float32 — min live p per chunk
     p_hi: np.ndarray          # (K,) float32 — max live p per chunk
     nout: np.ndarray          # (K,) float32 — 1.0 ⇔ chunk before Ē boundary
     ebar_chunk: int           # chunks [ebar_chunk:] lie fully inside Ē
     n_live: int               # E — real (non-padding) entries
-    order: np.ndarray = None  # gathered column j = base column order[j] (−1 pad)
+    order: np.ndarray         # gathered column j = base column order[j] (−1 pad)
+    width: int                # chunk width (= the kernel entry-block size)
+    store: Optional[CorpusStore] = None   # the gathered host copy
 
     @property
     def n_chunks(self) -> int:
         """K — number of uniform-width entry chunks."""
-        return self.store.n_chunks
+        return len(self.p_hat)
 
-    @property
-    def width(self) -> int:
-        """Chunk width (= the kernel entry-block size block_e)."""
-        return self.store.chunk_entries
+    def gather(self, index: InvertedIndex,
+               row_capacity: Optional[int] = None) -> CorpusStore:
+        """Build ``store``: the index's columns in ``order`` on the host."""
+        cap = index.n_sources if row_capacity is None else int(row_capacity)
+        self.store = index.store.gather_entries(
+            self.order, chunk_entries=self.width, capacity=cap)
+        return self.store
 
 
-def engine_chunks(
+def engine_order(
     index: InvertedIndex,
     n_buckets: int = 64,
-    row_capacity: Optional[int] = None,
     max_width: Optional[int] = None,
 ) -> EngineChunks:
-    """Build the engine's uniform-width chunk store from an index.
+    """The engine's chunking of an index, without its incidence.
 
     The chunk width is ``ceil(E / n_buckets)`` aligned up to the kernel tile
     edge (8), so ``n_buckets`` keeps its meaning as the p̂ granularity; the
@@ -1065,21 +1071,21 @@ def engine_chunks(
 
     The regions come from ``index.nonebar_mask``, so a committed index
     (base + delta chunks, Ē as a mask — DESIGN.md §7) chunks exactly like a
-    fresh one: the gather pulls each region's live columns wherever they
+    fresh one: ``order`` names each region's live columns wherever they
     physically sit, and the delta layout dissolves into the p-sorted order.
+    Only entry metadata is read here; ``EngineChunks.gather`` (host) or
+    ``devchunks.gather`` (device) moves the incidence.
     """
     nonebar = index.nonebar_mask
     live = index.live_mask
     non = np.nonzero(nonebar)[0]
     ebar = np.nonzero(live & ~nonebar)[0]
     n_live = len(non) + len(ebar)
-    cap = index.n_sources if row_capacity is None else int(row_capacity)
     if n_live == 0:
-        empty = index.store.gather_entries(np.zeros(0, np.int64), capacity=cap)
         z = np.zeros(0, np.float32)
-        return EngineChunks(store=empty, p_hat=z, p_lo=z, p_hi=z, nout=z,
-                            ebar_chunk=0, n_live=0,
-                            order=np.zeros(0, np.int64))
+        return EngineChunks(p_hat=z, p_lo=z, p_hi=z, nout=z, ebar_chunk=0,
+                            n_live=0, order=np.zeros(0, np.int64),
+                            width=index.store.chunk_entries)
 
     b = align_chunk(-(-n_live // max(int(n_buckets), 1)))
     if max_width is not None:
@@ -1092,14 +1098,15 @@ def engine_chunks(
         order_pre, np.full(pad0, -1, np.int64),
         order_suf, np.full(pad1, -1, np.int64),
     ])
-    store = index.store.gather_entries(order, chunk_entries=b,
-                                       capacity=cap)
-    K = store.n_chunks
+    K = len(order) // b
     ebar_chunk = (len(non) + pad0) // b
 
-    p_hat, p_lo, p_hi = _segment_p_stats(
-        store.entry_p, store.entry_item >= 0, np.arange(K + 1) * b)
+    used = order >= 0
+    p = np.zeros(len(order), np.float32)
+    p[used] = index.entry_p[order[used]]
+    p_hat, p_lo, p_hi = _segment_p_stats(p, used, np.arange(K + 1) * b)
     nout = (np.arange(K) < ebar_chunk).astype(np.float32)
-    return EngineChunks(store=store, p_hat=p_hat, p_lo=p_lo, p_hi=p_hi,
-                        nout=nout, ebar_chunk=ebar_chunk, n_live=n_live,
-                        order=order)
+    return EngineChunks(p_hat=p_hat, p_lo=p_lo, p_hi=p_hi, nout=nout,
+                        ebar_chunk=ebar_chunk, n_live=n_live, order=order,
+                        width=b)
+

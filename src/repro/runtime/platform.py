@@ -1,7 +1,11 @@
 """Platform setup + pipeline autotuning for launch and benchmarks.
 
-Three concerns the tiled engine's async pipeline (DESIGN.md §11) pushes to
-process startup:
+Four concerns the tiled engine (DESIGN.md §6, §11) pushes to process
+startup:
+
+* **Host allocator** — ``keep_host_arrays_on_heap`` has glibc serve the
+  chunk-sized arrays a pass allocates and frees from its heap, so later
+  passes reuse their pages instead of faulting fresh ones in.
 
 * **Persistent compilation cache** — ``enable_compile_cache`` keeps
   compiled programs across processes: in ``JAX_COMPILATION_CACHE_DIR``
@@ -21,6 +25,7 @@ process startup:
 """
 from __future__ import annotations
 
+import ctypes
 import json
 import os
 from pathlib import Path
@@ -36,6 +41,42 @@ AUTOTUNE_DIR = ".autotune"
 #: the working directory. The path is part of the cache key, so it must not
 #: move between runs.
 COMPILE_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+
+
+#: glibc ``mallopt`` parameters (malloc.h).
+_M_TRIM_THRESHOLD, _M_MMAP_THRESHOLD = -1, -3
+#: Host allocations under this size come from glibc's heap: a Book-full
+#: pass's store chunks (about 1.7 MB) and its per-entry and per-row
+#: temporaries.
+HEAP_MMAP_BYTES = 32 << 20
+#: Freed heap kept for reuse before glibc returns it to the system.
+HEAP_TRIM_BYTES = 1 << 30
+_heap_kept = False
+
+
+def keep_host_arrays_on_heap() -> bool:
+    """Serve host allocations under ``HEAP_MMAP_BYTES`` from glibc's heap,
+    and keep up to ``HEAP_TRIM_BYTES`` of freed heap for reuse; once per
+    process.
+
+    A detection pass allocates and frees hundreds of store chunks and
+    claim-sized temporaries. glibc maps each one afresh unless a freed
+    mapping of a larger size has raised its threshold, so every pass
+    page-faults them in again: on a TPU v5e host that made a Book-full
+    index build take nearly twice as long (PERF.md, Findings). Returns
+    False where the C library has no ``mallopt`` (not glibc).
+    """
+    global _heap_kept
+    if not _heap_kept:
+        try:
+            mallopt = ctypes.CDLL(None).mallopt
+        except (OSError, AttributeError):
+            return False
+        mallopt.argtypes = (ctypes.c_int, ctypes.c_int)
+        mallopt.restype = ctypes.c_int
+        _heap_kept = bool(mallopt(_M_MMAP_THRESHOLD, HEAP_MMAP_BYTES)
+                          and mallopt(_M_TRIM_THRESHOLD, HEAP_TRIM_BYTES))
+    return _heap_kept
 
 
 def enable_compile_cache() -> str:
@@ -146,6 +187,7 @@ def autotune(
     return out
 
 
-__all__ = ["AUTOTUNE_DIR", "COMPILE_CACHE_DIR", "autotune",
-           "enable_compile_cache", "load_autotune", "set_host_device_count",
-           "set_platform"]
+__all__ = ["AUTOTUNE_DIR", "COMPILE_CACHE_DIR", "HEAP_MMAP_BYTES",
+           "HEAP_TRIM_BYTES", "autotune",
+           "enable_compile_cache", "keep_host_arrays_on_heap",
+           "load_autotune", "set_host_device_count", "set_platform"]

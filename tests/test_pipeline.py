@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from repro.core import CopyConfig, DetectionEngine, build_index
-from repro.core import tilecache
+from repro.core import devchunks, tilecache
 from repro.core.pipeline import ChunkPrefetcher, PipelineStageError
 from repro.core.serving import DetectRequest, DetectionService
 from repro.core.types import ClaimsDataset
@@ -119,22 +119,21 @@ def test_prefetcher_slow_stage_keeps_order_and_counts_waits():
 def test_engine_stage_fault_is_typed_and_engine_reusable():
     """A staging fault inside detect() raises PipelineStageError; the same
     engine object then serves the next detect normally (no stranded worker,
-    no corrupted pipeline state)."""
+    no corrupted pipeline state). The fault is injected into both ways of
+    staging a group: the device gather and the host slab's transfer."""
     ds, p = _world(3)
     idx = build_index(ds, p, CFG)
     eng = DetectionEngine(CFG, mode="bucketed", tile=32, prefetch_depth=2)
     ref = eng.detect(ds, p, index=idx)
     n0 = threading.active_count()
-    orig = DetectionEngine._stage_v
 
-    def broken(self, v_np, dtype):
+    def broken(*a, **kw):
         raise faults.InjectedFault("injected staging fault")
-    DetectionEngine._stage_v = broken
-    try:
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(DetectionEngine, "_stage_v", broken)
+        mp.setattr(devchunks, "gather", broken)
         with pytest.raises(PipelineStageError, match="injected staging"):
             eng.detect(ds, p, index=idx)
-    finally:
-        DetectionEngine._stage_v = orig
     deadline = time.monotonic() + 5
     while threading.active_count() > n0 and time.monotonic() < deadline:
         time.sleep(0.01)

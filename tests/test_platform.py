@@ -51,3 +51,19 @@ def test_compile_cache_location(from_env, tmp_path):
         assert any(cache.iterdir())
     else:
         assert ".jax_cache/" in (ROOT / ".gitignore").read_text().split()
+
+
+def test_engine_keeps_host_arrays_on_heap(monkeypatch):
+    """glibc serves chunk-sized arrays from its heap once an engine exists;
+    setting it twice is a no-op."""
+    import repro.core.engine as engine_mod
+    from repro.core import CopyConfig, DetectionEngine
+    from repro.runtime import platform
+
+    assert platform.keep_host_arrays_on_heap() is True
+    assert platform.keep_host_arrays_on_heap() is True
+    calls = []
+    monkeypatch.setattr(engine_mod, "keep_host_arrays_on_heap",
+                        lambda: calls.append(1))
+    DetectionEngine(CopyConfig(alpha=0.1, s=0.8, n=50.0))
+    assert calls == [1]

@@ -7,7 +7,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import CopyConfig, DetectionEngine, build_index
 from repro.core.bucketed import index_detect_exact
-from repro.core.index import engine_chunks
+from repro.core.index import engine_order
 from repro.core.store import align_chunk
 from repro.core.types import ClaimsDataset
 from repro.data.claims import (
@@ -89,10 +89,12 @@ def test_slice_and_gather_bit_exact(seed, chunk, lo, width):
 
 
 def test_engine_chunks_layout():
-    """engine_chunks: uniform width, chunk-aligned Ē boundary, live p̂ stats."""
+    """engine_order + gather: uniform width, chunk-aligned Ē boundary, live
+    p̂ stats."""
     ds, p = _random_world(5, n_src=32, n_items=120)
     idx = build_index(ds, p, CFG, chunk_entries=16)
-    ech = engine_chunks(idx, n_buckets=8, row_capacity=40)
+    ech = engine_order(idx, n_buckets=8)
+    ech.gather(idx, row_capacity=40)
     b = ech.width
     assert b % 8 == 0
     assert ech.store.capacity == 40
@@ -126,7 +128,8 @@ def test_copyscore_store_matches_dense_kernel():
 
     ds, p = _random_world(9, n_src=24, n_items=100)
     idx = build_index(ds, p, CFG, chunk_entries=16)
-    ech = engine_chunks(idx, n_buckets=6)
+    ech = engine_order(idx, n_buckets=6)
+    ech.gather(idx)
     dense = ech.store.to_dense().astype(np.float32)
     c_d, n_d = copyscore(dense, ech.p_hat, ds.accuracy,
                          s=CFG.s, n_false=CFG.n, block_e=ech.width,

@@ -17,7 +17,7 @@ from collections import Counter, defaultdict
 import numpy as np
 import pytest
 
-from repro.core import CopyConfig
+from repro.core import CopyConfig, devchunks
 from repro.core.engine import DetectionEngine
 from repro.core.pipeline import ChunkPrefetcher
 from repro.core.serving import DetectRequest, DetectionService, serve_batch
@@ -316,27 +316,71 @@ def test_one_pass_yields_the_span_tree():
 
 
 def test_h2d_bytes_equal_the_staged_slabs():
+    """What a pass ships host to device is the base store's upload, once,
+    inside ``engine.chunk_gather``: every chunk at the tile-padded rows,
+    and no group slab staged from the host."""
     svc = _service()
     _serve(svc, [90])
     eng = svc.engine
-    staged = []
-    real = eng._stage_v
+    staged, shipped = [], []
+    real_stage, real_upload = eng._stage_v, devchunks.upload
 
     def counting(v_np, dtype):
         staged.append(np.asarray(v_np, np.dtype(dtype)).nbytes)
-        return real(v_np, dtype)
+        return real_stage(v_np, dtype)
+
+    def uploading(store, s_pad, sharding):
+        out = real_upload(store, s_pad, sharding)
+        assert store.capacity >= s_pad
+        assert out[1] == store.n_chunks * store.chunk_entries * s_pad
+        shipped.append(out[1])
+        return out
 
     eng._stage_v = counting
-    t0 = time.perf_counter()
-    _serve(svc, [5, 6])
+    devchunks.upload = uploading
+    try:
+        t0 = time.perf_counter()
+        _serve(svc, [5, 6])
+    finally:
+        devchunks.upload = real_upload
     recs = trace.records(t0)
     (batch,) = [r for r in recs if r.name == "service.batch"]
+    (gather,) = [r for r in recs if r.name == "engine.chunk_gather"]
     h2d = [r for r in recs if r.name == "engine.h2d_bytes"]
-    assert staged and len(h2d) == len(staged)
-    assert sum(r.value for r in h2d) == sum(staged)
+    assert not staged and len(shipped) == 1
+    assert [r.value for r in h2d] == shipped
+    assert all(gather.t0 <= r.t0 <= gather.t1 for r in h2d)
     assert all(batch.t0 <= r.t0 <= batch.t1 for r in h2d)
     stage = [r for r in recs if r.name == "engine.scan.stage"]
-    assert sorted(r.attrs["bytes"] for r in stage) == sorted(staged)
+    assert stage and not any("bytes" in r.attrs for r in stage)
+
+
+def test_device_gathered_chunks_and_the_gather_attribute():
+    """``engine.device_gathered_chunks`` counts the chunks the scan took
+    from the resident base, one event per group on the stage thread;
+    ``engine.chunk_gather`` names where the slabs were gathered."""
+    svc = _service()
+    _serve(svc, [90])
+    t0 = time.perf_counter()
+    _serve(svc, [7, 8])
+    recs = trace.records(t0)
+    st = svc.engine.last_stats
+    (gather,) = [r for r in recs if r.name == "engine.chunk_gather"]
+    assert gather.attrs["gather"] == st["gather"] == "device"
+    got = [r for r in recs if r.name == "engine.device_gathered_chunks"]
+    stages = {r.id for r in recs if r.name == "engine.scan.stage"}
+    assert got and all(r.parent in stages for r in got)
+    assert sum(r.value for r in got) == st["chunks"]
+
+    # the host gather where the resident base would not fit the device
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(devchunks, "fits", lambda devices, nbytes: False)
+        t0 = time.perf_counter()
+        _serve(svc, [9])
+    recs = trace.records(t0)
+    (gather,) = [r for r in recs if r.name == "engine.chunk_gather"]
+    assert gather.attrs["gather"] == svc.engine.last_stats["gather"] == "host"
+    assert not [r for r in recs if r.name == "engine.device_gathered_chunks"]
 
 
 def test_index_build_span_counts_entries():
