@@ -1,4 +1,6 @@
-"""The loop kinds a traffic file can name.
+"""The built-in loop kinds a traffic file can name; a loop of a cell's
+own is the ``Driver`` subclass in ``bench/loops/<loop>.py``
+(``harness.loop_class``).
 
 * ``closed``: ``clients`` callers, each with one detect request
   outstanding; the window ends when the last request issued before the

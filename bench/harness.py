@@ -8,9 +8,18 @@ metric is a file found by name:
 * ``bench/configs/<config>.json``: a deployment (corpus spec, model,
   service settings, guarantees);
 * ``bench/traffic/<traffic>.json``: a traffic mix, run by the loop its
-  ``loop`` key names (``closed`` or ``corpus``, in ``drivers.py``);
+  ``loop`` key names: ``closed`` or ``corpus`` (``drivers.DRIVERS``), or
+  the one ``drivers.Driver`` subclass that ``bench/loops/<loop>.py``
+  defines;
 * ``bench/limits/<cell>.json``: the limits of the numbers compared;
-* ``bench/metrics/<metric>.py``: a per-layer metric reader, ``read(run)``.
+* ``bench/metrics/<metric>.py``: a per-layer metric reader, ``read(run)``;
+* ``bench/kernels/<kernel>.json``: ``{"pattern", "why", "workloads"}``,
+  a regular expression over the device operations of one kernel, counted
+  in the cells that ``workloads`` names; its time is
+  ``run.trace["kernel_s"][<kernel>]``.
+
+A configuration or traffic file may also carry a ``tiny`` block, the cut
+that the CPU rehearsals (``bench/tests/tiny.py``) merge into it.
 """
 from __future__ import annotations
 
@@ -18,6 +27,7 @@ import functools
 import importlib
 import importlib.util
 import json
+import sys
 import threading
 import time
 from contextlib import contextmanager, nullcontext
@@ -44,9 +54,10 @@ HOOKS = [
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-#: Device operations of the copyscore kernel: the Pallas kernel is the only
-#: TPU custom call the program runs, named after its kernel function. (XLA's
-#: own ``AllocateBuffer`` custom calls do not match.)
+#: Device operations of the copyscore kernel: the Pallas kernel named after
+#: its kernel function, and every TPU custom call that no kernel of the
+#: cell's own claims (``kernel_patterns``). (XLA's own ``AllocateBuffer``
+#: custom calls do not match.)
 KERNEL_PATTERN = r"copyscore|tpu_custom_call"
 
 
@@ -74,6 +85,8 @@ class Cell:
     limits: dict
     end_to_end: list
     per_layer: list
+    loop: type                  # the drivers.Driver subclass that runs it
+    root: Path = ROOT
 
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
@@ -99,17 +112,67 @@ def cell_from(w: dict, bench: dict, root: Path = ROOT) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if name in m.get("workloads", [])
                  or ("workloads" not in m and m["moves"] in e2e_names)]
-    return Cell(name, w, config, traffic, limits, e2e, per_layer)
+    return Cell(name, w, config, traffic, limits, e2e, per_layer,
+                loop_class(traffic["loop"], root), root)
+
+
+def _module(path: Path, prefix: str):
+    """The Python file ``path`` imported as a module of its own."""
+    name = prefix + path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def loops(root: Path = ROOT) -> list:
+    """The loop names a traffic file can give."""
+    import drivers
+
+    found = (root / "bench" / "loops").glob("*.py")
+    return sorted(drivers.DRIVERS) + sorted(p.stem for p in found)
+
+
+def loop_class(loop: str, root: Path = ROOT) -> type:
+    """The driver class of the loop ``loop``: ``drivers.DRIVERS[loop]``,
+    else the one ``drivers.Driver`` subclass that ``bench/loops/<loop>.py``
+    defines. An unknown loop is a KeyError, as an unknown workload is."""
+    import drivers
+
+    if loop in drivers.DRIVERS:
+        return drivers.DRIVERS[loop]
+    path = root / "bench" / "loops" / f"{loop}.py"
+    if not path.is_file():
+        raise KeyError(f"no loop {loop!r}; loops: {loops(root)}")
+    mod = _module(path, "bench_loop_")
+    found = [c for c in vars(mod).values()
+             if isinstance(c, type) and issubclass(c, drivers.Driver)
+             and c.__module__ == mod.__name__]
+    if len(found) != 1:
+        raise KeyError(f"{path.name} defines {len(found)} drivers.Driver "
+                       f"subclasses, not one")
+    return found[0]
 
 
 def metric_reader(name: str, root: Path = ROOT):
     """The ``read`` function of ``bench/metrics/<name>.py``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(root / "bench" / "metrics" / f"{name}.py",
+                   "bench_metric_").read
+
+
+def kernel_patterns(cell: Cell) -> dict:
+    """The device-operation pattern of each kernel the cell's trace counts:
+    every ``bench/kernels/<kernel>.json`` whose ``workloads`` name the
+    cell, and copyscore's, the catch-all (``trace_reduce.reduce_planes``)
+    that yields to them."""
+    pats = {}
+    for p in sorted((cell.root / "bench" / "kernels").glob("*.json")):
+        k = json.loads(p.read_text())
+        if cell.name in k["workloads"]:
+            pats[p.stem] = k["pattern"]
+    pats["copyscore"] = KERNEL_PATTERN
+    return pats
 
 
 # ---------------------------------------------------------------------------
@@ -298,10 +361,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     """Set up, measure, check; returns the result-line object. With
     ``control`` (a dtype) it also holds ``control``: the numbers compared
     for the reference in that precision in the program's place."""
-    import drivers
-
     spans = Spans(annotate=trace)
-    driver = drivers.DRIVERS[cell.traffic["loop"]](cell, seed, spans)
+    driver = cell.loop(cell, seed, spans)
     try:
         return _run(cell, driver, spans, seconds, trace, devices, t_start,
                     control)
@@ -349,9 +410,10 @@ def _run(cell, driver, spans, seconds, trace, devices, t_start,
     if trace:
         import roofline
         run.peak = roofline.peaks(devices[0].device_kind)
-        run.trace = _reduce_trace(trace_dir, len(devices))
+        run.trace = _reduce_trace(trace_dir, len(devices),
+                                  kernel_patterns(cell))
         for m in cell.per_layer:
-            value = metric_reader(m["name"])(run)
+            value = metric_reader(m["name"], cell.root)(run)
             if value is None:
                 log(f"metric {m['name']}: nothing to read")
                 continue
@@ -388,8 +450,9 @@ def _run(cell, driver, spans, seconds, trace, devices, t_start,
     return result
 
 
-def _reduce_trace(trace_dir: str, n_devices: int) -> dict:
-    """Reduce the run's trace and delete it."""
+def _reduce_trace(trace_dir: str, n_devices: int, patterns: dict) -> dict:
+    """Reduce the run's trace, with the kernels' ``patterns`` (copyscore's
+    the catch-all), and delete it."""
     import glob
     import shutil
 
@@ -400,7 +463,8 @@ def _reduce_trace(trace_dir: str, n_devices: int) -> dict:
             raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
         red = trace_reduce.reduce_planes(
             trace_reduce.planes_from_file(paths[0]),
-            kernel_patterns={"copyscore": KERNEL_PATTERN}, n_devices=n_devices)
+            kernel_patterns=patterns, n_devices=n_devices,
+            catch_all="copyscore")
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
     log(f"trace: busy {red['busy_s']:.4f} s of {red['window_s']:.4f} s, "

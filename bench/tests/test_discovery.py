@@ -1,5 +1,5 @@
-"""Cells, configurations, traffic, limits and metric readers are found by
-name; the harness refuses to run without a TPU."""
+"""Cells, configurations, traffic, loops, limits and metric readers are
+found by name; the harness refuses to run without a TPU."""
 import json
 import os
 import subprocess
@@ -16,8 +16,11 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 
 @pytest.mark.parametrize("name", CELLS)
 def test_each_cell_finds_its_files_and_metrics(name):
+    import drivers
+
     cell = harness.load_cell(name)
-    assert cell.traffic["loop"] in ("closed", "corpus")
+    assert cell.traffic["loop"] in harness.loops()
+    assert issubclass(cell.loop, drivers.Driver)
     assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}
     assert cell.per_layer and cell.limits
     for m in cell.per_layer:

@@ -17,7 +17,12 @@ CELLS = sorted({cell for _, cell in READERS})
 
 
 def test_every_program_reader_is_listed():
-    assert len(READERS) == 9
+    """Every reader file of the program's records is a metric that
+    ``BENCHMARK.json`` lists with a program source."""
+    files = {p.name[:-len(".py")]
+             for p in (harness.ROOT / "bench" / "metrics").glob("*.py")
+             if "program_trace" in p.read_text()}
+    assert files == {name for name, _ in READERS}
 
 
 @pytest.mark.parametrize("metric,cell", READERS)
@@ -66,3 +71,11 @@ def test_without_the_recorder_reads_none(metric, cell, rehearsed,
     monkeypatch.delattr(repro.utils, "trace", raising=False)
     assert harness.metric_reader(metric)(rehearsed[cell]) is None
 
+
+def test_device_gathered_chunks_are_the_chunks_of_each_pass(rehearsed):
+    """The counter sums, over a pass's chunk groups, the chunks gathered on
+    the device: every chunk of the pass, ``last_stats["chunks"]``."""
+    run = rehearsed["book_full.serve"]
+    chunks = [p["stats"]["chunks"] for p in run.passes_in_window()]
+    got = harness.metric_reader("device_gathered_chunks.serve")(run)
+    assert got == pytest.approx(sum(chunks) / len(chunks))

@@ -5,12 +5,16 @@ The control (the reference computed in bfloat16 in the program's place)
 and each fault must turn ``correct`` false; the sound program must leave
 it true.
 """
+import json
+
 import numpy as np
 import pytest
 
+import harness
 import tiny
 
-CELLS = ["book_full.serve", "book_full.corpus"]
+CELLS = [w["name"] for w in json.loads(
+    (harness.ROOT / "BENCHMARK.json").read_text())["workloads"]]
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -93,7 +97,6 @@ def test_fault_is_caught(name, fault, monkeypatch):
 
 def test_same_seed_same_inputs():
     import drivers
-    import harness
 
     cell = tiny.tiny_cell("book_full.serve")
     a = drivers.Driver(cell, 7, harness.Spans())
@@ -110,7 +113,6 @@ def test_byte_budget_fixes_the_chunk_width(budget_width, want):
     width each batch's entry count gives, so every pass runs one kernel
     shape; without the cap the width follows the batch."""
     import drivers
-    import harness
 
     cell = tiny.tiny_cell("book_full.serve")
     cell.config["engine"]["n_buckets"] = 1

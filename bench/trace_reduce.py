@@ -4,7 +4,9 @@
   device's ``XLA Ops`` line, clipped to the traced window and averaged
   over the devices;
 * kernel seconds: the summed device durations of the operations whose name
-  matches a kernel's pattern;
+  matches a kernel's pattern; an operation that two kernels' patterns
+  match is an error, unless one of the two is the catch-all kernel, which
+  takes only what no other kernel matches;
 * ``device_ops``: the ten operations with the most device time, by their
   HLO instruction name (a TPU trace names an event by its whole HLO text);
 * ``idle_gaps``: the device's idle time inside the window, attributed to
@@ -52,8 +54,9 @@ def planes_from_file(path: str):
 
 
 def reduce_planes(planes, kernel_patterns: dict | None = None,
-                  n_devices: int = 1) -> dict:
-    """The numbers above from ``planes_from_file`` output."""
+                  n_devices: int = 1, catch_all: str | None = None) -> dict:
+    """The numbers above from ``planes_from_file`` output; ``catch_all``
+    names the kernel of ``kernel_patterns`` that yields to the others."""
     host_spans = []
     window = None
     for name, lines in planes:
@@ -88,10 +91,15 @@ def reduce_planes(planes, kernel_patterns: dict | None = None,
                 continue
             ivs.append((s, e))
             op_time[_op_name(name)] += (e - s) * 1e-9
-            for k, pat in patterns.items():
-                if pat.search(name):
-                    kernel_s[k] += (e - s) * 1e-9
-                    kernel_names[k].add(name)
+            hits = [k for k, pat in patterns.items() if pat.search(name)]
+            if len(hits) > 1 and catch_all in hits:
+                hits.remove(catch_all)
+            if len(hits) > 1:
+                raise ValueError(f"device op {name!r} matches the kernels "
+                                 f"{hits}")
+            for k in hits:
+                kernel_s[k] += (e - s) * 1e-9
+                kernel_names[k].add(name)
         busy = _union(ivs)
         busy_total += sum(e - s for s, e in busy) * 1e-9
         prev = w0
