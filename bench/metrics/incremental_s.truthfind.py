@@ -1,0 +1,6 @@
+"""INCREMENTAL rounds (``engine.incremental``), seconds per run."""
+import program_trace
+
+
+def read(run):
+    return program_trace.span_per_pass(run, 'engine.incremental')

@@ -39,11 +39,7 @@ from repro.core.bound import bound_detect, hybrid_detect
 from repro.core.bucketed import bucketed_index_detect, index_detect_exact
 from repro.core.engine import DetectionEngine, EngineOptions
 from repro.core.fagin import fagin_input
-from repro.core.incremental import (
-    incremental_detect,
-    make_incremental_state,
-    rescore_pairs_exact,
-)
+from repro.core.incremental import incremental_detect, make_incremental_state
 from repro.core.index import (
     CommitInfo,
     RetractInfo,
@@ -56,7 +52,7 @@ from repro.core.index import (
     rollback_commit,
 )
 from repro.core.sampling import sample_by_cell, sample_by_item, scale_sample
-from repro.core.scoring import pairwise_detect
+from repro.core.scoring import pairwise_detect, rescore_pairs_exact
 from repro.core.serving import (
     CircuitBreaker,
     DeadlineExceeded,

@@ -47,11 +47,12 @@ from repro.core.index import (
 from repro.core.scoring import (
     bucket_score_deltas,
     decide_copying,
-    pair_scores_subset,
     posterior_independence,
+    rescore_pairs_exact,
     score_same,
 )
 from repro.core.types import ClaimsDataset, CopyConfig, DetectionResult
+from repro.utils import trace
 from repro.utils.counters import ComputeCounter
 
 
@@ -183,6 +184,7 @@ def _bound_stream(idx: InvertedIndex, b: BucketedIndex, acc, l_counts, d_src,
     return carry
 
 
+@trace.spanned("engine.bound")
 def bound_detect(
     ds: ClaimsDataset,
     p_claim: np.ndarray,
@@ -247,9 +249,7 @@ def bound_detect(
             & (np.abs(z) < rescore_margin + np.maximum(err, err.T))
             & np.triu(np.ones((S, S), bool), 1))
     pi, pj = np.nonzero(near)
-    if len(pi):
-        c_fwd[pi, pj] = pair_scores_subset(ds, p_claim, cfg, pi, pj)
-        c_fwd[pj, pi] = pair_scores_subset(ds, p_claim, cfg, pj, pi)
+    rescore_pairs_exact(ds, p_claim, cfg, pi, pj, c_fwd)
 
     step4 = np.array(decide_copying(jnp.asarray(c_fwd), jnp.asarray(c_fwd.T), cfg))
     copying = np.where(decided != 0, decided > 0, step4) & considered

@@ -65,11 +65,7 @@ from repro.core.bound import bound_detect
 from repro.core.bucketed import index_detect_exact
 from repro.core.distributed import sharded_tile_scores, sharded_tile_scores_2d
 from repro.core.pipeline import ChunkPrefetcher, PipelineStageError
-from repro.core.incremental import (
-    incremental_detect,
-    make_incremental_state,
-    rescore_pairs_exact,
-)
+from repro.core.incremental import incremental_detect, make_incremental_state
 from repro.core.index import InvertedIndex, build_index, engine_order
 from repro.core.sampling import sample_by_cell, sample_by_item, scale_sample
 from repro.core.shardplan import (
@@ -87,6 +83,7 @@ from repro.core.scoring import (
     decide_copying_np,
     pairwise_detect,
     posterior_independence_np,
+    rescore_pairs_exact,
 )
 from repro.core.types import ClaimsDataset, CopyConfig, DetectionResult
 from repro.runtime.platform import keep_host_arrays_on_heap

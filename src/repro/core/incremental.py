@@ -39,8 +39,8 @@ from repro.core.index import (
 )
 from repro.core.scoring import (
     decide_copying_np,
-    pair_scores_subset,
     posterior_independence_np,
+    rescore_pairs_exact,
     score_same_np,
 )
 from repro.core.types import ClaimsDataset, CopyConfig, DetectionResult
@@ -69,43 +69,6 @@ class IncrementalState:
     pass1_settled: float = 1.0
     err: np.ndarray = None        # (S,S) accumulated p̂-error bound on c_hat
                                   # (0 where a round has rescored exactly)
-
-
-@trace.spanned("engine.rescore")
-def rescore_pairs_exact(
-    ds: ClaimsDataset,
-    p_claim: np.ndarray,
-    cfg: CopyConfig,
-    pi: np.ndarray,
-    pj: np.ndarray,
-    c_fwd: np.ndarray,
-) -> int:
-    """Gathered dense exact rescore of an explicit flip-candidate pair list.
-
-    This is the batched op DESIGN.md §2.3 collapses the paper's §V
-    compensation passes into, shared by every caller that must replace
-    approximate pair scores with exact ones: INCREMENTAL's flip candidates,
-    the engine's error-bounded near-threshold pairs (DESIGN.md §3 step 4),
-    and SAMPLE-THEN-VERIFY's candidate set (DESIGN.md §4).
-
-    Args:
-      ds, p_claim, cfg: the *full* dataset, per-claim truth probabilities
-        (S, D), and model config the exact scores are computed against.
-      pi, pj: (P,) int arrays of source indices — the unordered pairs to
-        rescore (each listed once; both orientations are written).
-      c_fwd: (S, S) float32 C→ matrix, mutated in place at [pi, pj] and
-        [pj, pi] with exact Eq. 2–8 scores over all shared items.
-
-    Returns the number of pairs rescored (0 for an empty list).
-    """
-    trace.annotate(pairs=len(pi))
-    if len(pi) == 0:
-        return 0
-    both = pair_scores_subset(ds, p_claim, cfg, np.concatenate([pi, pj]),
-                              np.concatenate([pj, pi]))
-    c_fwd[pi, pj] = both[:len(pi)]
-    c_fwd[pj, pi] = both[len(pi):]
-    return len(pi)
 
 
 def make_incremental_state(
@@ -159,6 +122,7 @@ def make_incremental_state(
     return result, state
 
 
+@trace.spanned("engine.incremental")
 def incremental_detect(
     ds: ClaimsDataset,
     p_claim: np.ndarray,
@@ -174,85 +138,96 @@ def incremental_detect(
     E = idx.n_entries
     acc_new = ds.accuracy.astype(np.float64)
 
-    # new entry probabilities via any provider's claim (padding columns of a
-    # committed store have no providers — clamp the lookup and zero their
-    # deltas so they never join the big/small classification)
-    live = idx.entry_item >= 0
-    p_new = p_claim[state.first_provider,
-                    np.maximum(idx.entry_item, 0)].astype(np.float32)
-    p_new = np.where(live, p_new, state.p_old)
-    score_new = score_same_np(
-        p_new.astype(np.float64), state.a1_ref, state.a2_ref, cfg.s, cfg.n
-    ).astype(np.float32)
-    delta = np.where(live, score_new - state.score_old, 0.0)
-    big = np.abs(delta) > rho
-    small_dec = (~big) & (delta < 0)
-    small_inc = (~big) & (delta > 0)
+    with trace.span("incremental.pass1"):
+        # new entry probabilities via any provider's claim (padding columns of a
+        # committed store have no providers — clamp the lookup and zero their
+        # deltas so they never join the big/small classification)
+        live = idx.entry_item >= 0
+        p_new = p_claim[state.first_provider,
+                        np.maximum(idx.entry_item, 0)].astype(np.float32)
+        p_new = np.where(live, p_new, state.p_old)
+        score_new = score_same_np(
+            p_new.astype(np.float64), state.a1_ref, state.a2_ref, cfg.s, cfg.n
+        ).astype(np.float32)
+        delta = np.where(live, score_new - state.score_old, 0.0)
+        big = np.abs(delta) > rho
+        small_dec = (~big) & (delta < 0)
+        small_inc = (~big) & (delta > 0)
 
-    # ---- pass 1a: exact deltas from big-change entries -------------------
-    d_c = np.zeros((S, S), np.float64)
-    values_examined = 0
-    for e in np.nonzero(big)[0]:
-        provs = idx.providers(e)
-        if len(provs) < 2:
-            continue
-        a_new = acc_new[provs]
-        a_old = state.acc_old.astype(np.float64)[provs]
-        f_new = score_same_np(float(p_new[e]), a_new[:, None], a_new[None, :], cfg.s, cfg.n)
-        f_old = score_same_np(float(state.p_old[e]), a_old[:, None], a_old[None, :], cfg.s, cfg.n)
-        sub = np.ix_(provs, provs)
-        # only update pairs whose decision point lies after this entry
-        gate = state.dec_bucket[sub] >= state.entry_bucket[e]
-        d_c[sub] += np.where(gate, f_new - f_old, 0.0)
-        values_examined += int(np.triu(gate, 1).sum())
+        # ---- pass 1a: exact deltas from big-change entries -------------------
+        d_c = np.zeros((S, S), np.float64)
+        values_examined = 0
+        for e in np.nonzero(big)[0]:
+            provs = idx.providers(e)
+            if len(provs) < 2:
+                continue
+            a_new = acc_new[provs]
+            a_old = state.acc_old.astype(np.float64)[provs]
+            f_new = score_same_np(float(p_new[e]), a_new[:, None],
+                                  a_new[None, :], cfg.s, cfg.n)
+            f_old = score_same_np(float(state.p_old[e]), a_old[:, None],
+                                  a_old[None, :], cfg.s, cfg.n)
+            sub = np.ix_(provs, provs)
+            # only update pairs whose decision point lies after this entry
+            gate = state.dec_bucket[sub] >= state.entry_bucket[e]
+            d_c[sub] += np.where(gate, f_new - f_old, 0.0)
+            values_examined += int(np.triu(gate, 1).sum())
 
-    # ---- pass 1b: conservative batched bound for small changes -----------
-    d_rho_dec = float(-delta[small_dec].min()) if small_dec.any() else 0.0
-    d_rho_inc = float(delta[small_inc].max()) if small_inc.any() else 0.0
+        # ---- pass 1b: conservative batched bound for small changes -----------
+        d_rho_dec = float(-delta[small_dec].min()) if small_dec.any() else 0.0
+        d_rho_inc = float(delta[small_inc].max()) if small_inc.any() else 0.0
 
-    def _masked_counts(mask: np.ndarray) -> np.ndarray:
-        # Σ_chunks V_c[:, m] V_c[:, m]ᵀ — per-chunk partial sums of 0/1
-        # products are exact integers in f32, bit-equal to the dense matmul
-        out = np.zeros((S, S), np.float32)
-        if not mask.any():
+        def _masked_counts(mask: np.ndarray) -> np.ndarray:
+            # Σ_chunks V_c[:, m] V_c[:, m]ᵀ — per-chunk partial sums of 0/1
+            # products are exact integers in f32, bit-equal to the dense matmul
+            out = np.zeros((S, S), np.float32)
+            if not mask.any():
+                return out
+            for ch in idx.store.iter_chunks():
+                m = mask[ch.start: ch.start + ch.width]
+                if m.any():
+                    v = ch.V[:, m].astype(np.float32)
+                    out += v @ v.T
             return out
-        for ch in idx.store.iter_chunks():
-            m = mask[ch.start: ch.start + ch.width]
-            if m.any():
-                v = ch.V[:, m].astype(np.float32)
-                out += v @ v.T
-        return out
 
-    cnt_dec = _masked_counts(small_dec)
-    cnt_inc = _masked_counts(small_inc)
+        trace.count("incremental.big_entries", int(big.sum()))
+        trace.count("incremental.masked_entries",
+                    int(small_dec.sum() + small_inc.sum()))
+        with trace.span("incremental.masked_counts"):
+            cnt_dec = _masked_counts(small_dec)
+            cnt_inc = _masked_counts(small_inc)
 
-    c_base = state.c_hat.astype(np.float64) + d_c
-    # the bootstrap's accumulated p̂-error bound (zeroed wherever a previous
-    # round rescored exactly) — the keep rules must hold BEYOND it, so kept
-    # decisions stay provably exact for any index layout (DESIGN.md §7)
-    err = (state.err if state.err is not None
-           else np.zeros((S, S), np.float32)).astype(np.float64)
-    # worst case against the current decision
-    worst_down = c_base - d_rho_dec * cnt_dec - err
-    worst_up = c_base + d_rho_inc * cnt_inc + err
+        c_base = state.c_hat.astype(np.float64) + d_c
+        # the bootstrap's accumulated p̂-error bound (zeroed wherever a previous
+        # round rescored exactly) — the keep rules must hold BEYOND it, so kept
+        # decisions stay provably exact for any index layout (DESIGN.md §7)
+        err = (state.err if state.err is not None
+               else np.zeros((S, S), np.float32)).astype(np.float64)
+        # worst case against the current decision
+        worst_down = c_base - d_rho_dec * cnt_dec - err
+        worst_up = c_base + d_rho_inc * cnt_inc + err
 
-    log_ratio = np.log(cfg.alpha / cfg.beta)
-    was_copy = state.copying
-    # copying pairs stay decided if even the worst-case decrease keeps them over θ_cp
-    keep_copy = was_copy & (np.maximum(worst_down, worst_down.T) >= cfg.theta_cp)
-    # no-copying pairs stay decided if the worst-case increase keeps them independent
-    z_up = log_ratio + np.logaddexp(worst_up, worst_up.T)
-    keep_ind = (~was_copy) & (z_up < 0.0)
+        log_ratio = np.log(cfg.alpha / cfg.beta)
+        was_copy = state.copying
+        # copying pairs stay decided if even the worst-case decrease keeps
+        # them over θ_cp
+        keep_copy = was_copy & (np.maximum(worst_down, worst_down.T)
+                                >= cfg.theta_cp)
+        # no-copying pairs stay decided if the worst-case increase keeps
+        # them independent
+        z_up = log_ratio + np.logaddexp(worst_up, worst_up.T)
+        keep_ind = (~was_copy) & (z_up < 0.0)
 
-    big_acc = np.abs(acc_new - state.acc_old) > rho_acc
-    acc_flag = big_acc[:, None] | big_acc[None, :]
+        big_acc = np.abs(acc_new - state.acc_old) > rho_acc
+        acc_flag = big_acc[:, None] | big_acc[None, :]
 
-    candidates = state.considered & ~(keep_copy | keep_ind)
-    candidates |= state.considered & acc_flag
-    candidates &= np.triu(np.ones((S, S), bool), 1)
-    n_cand = int(candidates.sum())
-    n_considered = int(np.triu(state.considered, 1).sum())
-    state.pass1_settled = 1.0 - n_cand / max(n_considered, 1)
+        candidates = state.considered & ~(keep_copy | keep_ind)
+        candidates |= state.considered & acc_flag
+        candidates &= np.triu(np.ones((S, S), bool), 1)
+        n_cand = int(candidates.sum())
+        trace.count("incremental.candidates", n_cand)
+        n_considered = int(np.triu(state.considered, 1).sum())
+        state.pass1_settled = 1.0 - n_cand / max(n_considered, 1)
 
     # ---- passes 2–3 collapsed: exact rescore of candidates ---------------
     c_fwd = c_base.astype(np.float32)

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.types import ClaimsDataset, CopyConfig, DetectionResult
+from repro.utils import trace
 from repro.utils.counters import ComputeCounter
 
 
@@ -224,6 +225,43 @@ def pair_scores_subset(
                              jnp.asarray(pj[k:k + block]), cfg.s, cfg.n)
            for k in range(0, len(pi), block)]
     return np.concatenate([np.asarray(o) for o in out])[:n_pairs]
+
+
+@trace.spanned("engine.rescore")
+def rescore_pairs_exact(
+    ds: ClaimsDataset,
+    p_claim: np.ndarray,
+    cfg: CopyConfig,
+    pi: np.ndarray,
+    pj: np.ndarray,
+    c_fwd: np.ndarray,
+) -> int:
+    """Gathered dense exact rescore of an explicit flip-candidate pair list.
+
+    This is the batched op DESIGN.md §2.3 collapses the paper's §V
+    compensation passes into, shared by every caller that must replace
+    approximate pair scores with exact ones: INCREMENTAL's flip candidates,
+    the engine's error-bounded near-threshold pairs (DESIGN.md §3 step 4),
+    and SAMPLE-THEN-VERIFY's candidate set (DESIGN.md §4).
+
+    Args:
+      ds, p_claim, cfg: the *full* dataset, per-claim truth probabilities
+        (S, D), and model config the exact scores are computed against.
+      pi, pj: (P,) int arrays of source indices — the unordered pairs to
+        rescore (each listed once; both orientations are written).
+      c_fwd: (S, S) float32 C→ matrix, mutated in place at [pi, pj] and
+        [pj, pi] with exact Eq. 2–8 scores over all shared items.
+
+    Returns the number of pairs rescored (0 for an empty list).
+    """
+    trace.annotate(pairs=len(pi))
+    if len(pi) == 0:
+        return 0
+    both = pair_scores_subset(ds, p_claim, cfg, np.concatenate([pi, pj]),
+                              np.concatenate([pj, pi]))
+    c_fwd[pi, pj] = both[:len(pi)]
+    c_fwd[pj, pi] = both[len(pi):]
+    return len(pi)
 
 
 @partial(jax.jit, static_argnames=("s", "n"))

@@ -102,6 +102,30 @@ def copyscore_fused_ref(v, p_blk, acc, *, s, n_false, block_e=512,
 
 
 # ---------------------------------------------------------------------------
+# vote
+# ---------------------------------------------------------------------------
+
+@partial(jax.jit, static_argnames=("block_e",))
+def vote_fused_ref(lh3, v, sigma, *, block_e=512):
+    """Oracle for ``vote_fused_pallas``: the same three-part bfloat16
+    product and fold, one block of ``block_e`` value groups at a time, so
+    no (S, E) float32 array is made here either."""
+    S, E = v.shape
+    lh3 = lh3.astype(jnp.bfloat16)
+    sigma = sigma.astype(jnp.float32).reshape(S, 1)
+
+    def block(j):
+        vj = jax.lax.dynamic_slice_in_dim(v, j * block_e, block_e, axis=1)
+        x = vj.astype(jnp.bfloat16)
+        log_i = jnp.zeros((S, block_e), jnp.float32)
+        for part in (2, 1, 0):
+            log_i += jnp.dot(lh3[part], x, preferred_element_type=jnp.float32)
+        return jnp.sum(vj.astype(jnp.float32) * sigma * jnp.exp(log_i), axis=0)
+
+    return jax.lax.map(block, jnp.arange(E // block_e)).reshape(1, E)
+
+
+# ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------
 

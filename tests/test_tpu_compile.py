@@ -18,6 +18,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from repro.core.distributed import _sharded_tile_fn, _sharded_tile_fn_2d
 from repro.kernels.copyscore import copyscore_fused_pallas
+from repro.kernels.vote import vote_fused_pallas
 
 TILE = 256          # EngineOptions.tile
 BLOCK = 128         # pair block the engine picks for a 256 tile
@@ -90,3 +91,25 @@ def test_tile_scan_compiles_with_pallas_kernel(topo, layout):
         sds((K,), jnp.float32, s_spec), sds((n_tiles, 2), jnp.int32, t_spec)
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("s_pad,block_s", [(3200, 640), (384, 384)],
+                         ids=["book_full", "small"])
+def test_vote_kernel_compiles_for_v5e(topo, s_pad, block_s):
+    """The vote kernel at Book-full's padded sources (3,182 → 3,200, source
+    blocks of 640) over 64 value-group blocks; the program picks
+    ``block_s`` as ``truthfind._vote_block_s`` does."""
+    from repro.core.truthfind import VOTE_BLOCK_E, _vote_block_s
+
+    assert _vote_block_s(s_pad) == block_s
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    fn = jax.jit(lambda lh, v, sg: vote_fused_pallas(
+        lh, v, sg, block_s=block_s, block_e=VOTE_BLOCK_E))
+    compiled = fn.lower(sds((3, s_pad, s_pad), jnp.bfloat16),
+                        sds((s_pad, 64 * VOTE_BLOCK_E), jnp.int8),
+                        sds((s_pad, 1), jnp.float32)).compile()
+    assert "vote_fused_pallas" in compiled.as_text()
