@@ -25,7 +25,12 @@ HYBRID applies bounds only to pairs sharing more than ``l_threshold`` items
 The scan STREAMS buckets out of the chunked ``CorpusStore`` (DESIGN.md §6):
 one jitted per-bucket step is driven from the host, each step gathering only
 its bucket's entry columns — the ``(K, S, w)`` bucket tensor of the legacy
-``pad_buckets`` path is never materialized.
+``pad_buckets`` path is never materialized. Where the store is a plain
+``CorpusStore`` whose copy fits the device, its incidence is shipped once a
+call (``devchunks.upload``) and both reads come from that copy: the
+``considered`` co-occurrence is one int8 matmul with int32 counts, and each
+step slices its bucket out of it on the device. A row-sharded store, or one whose
+copy does not fit, is read on the host (DESIGN.md §2.2).
 """
 from __future__ import annotations
 
@@ -36,7 +41,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.sharding import SingleDeviceSharding
 
+from repro.core import devchunks
 from repro.core.index import (
     BucketedIndex,
     InvertedIndex,
@@ -51,6 +59,7 @@ from repro.core.scoring import (
     rescore_pairs_exact,
     score_same,
 )
+from repro.core.store import CorpusStore
 from repro.core.types import ClaimsDataset, CopyConfig, DetectionResult
 from repro.utils import trace
 from repro.utils.counters import ComputeCounter
@@ -141,12 +150,55 @@ def _bound_step(carry, v_k, p_k, m_next, delta_k, k, acc, l_counts, d_src,
             min_due, max_due, err, ve, bc)
 
 
+def _resident_incidence(store, S: int):
+    """``store``'s incidence on the default device, entry ``e`` in row ``e``
+    (``devchunks.upload``), or None where it stays on the host: a row-sharded
+    store, or a copy that does not fit. Counts the bytes shipped under
+    ``bound.h2d_bytes``."""
+    if not isinstance(store, CorpusStore) or not store.n_chunks:
+        return None
+    dev = jax.devices()[0]
+    # the copy and an upload batch, at most as much again for the masked
+    # operand of the co-occurrence, and its (S, S) int32 counts
+    need = 2 * devchunks.device_nbytes(store, S) + 4 * S * S
+    if not devchunks.fits([dev], need):
+        return None
+    resident, nbytes = devchunks.upload(store, S, SingleDeviceSharding(dev))
+    trace.count("bound.h2d_bytes", nbytes)
+    return resident
+
+
+@partial(jax.jit, static_argnames=("w", "dtype"))
+def _bucket_slab(resident, s0, s1, w, dtype):
+    """Entries ``[s0, s1)`` of the resident copy as an ``(S, w)`` slab, zero
+    elsewhere. The slice's start is clamped so that it ends inside the copy,
+    which puts the bucket's columns later in the slab; the step only sums
+    over the columns, in exact integers, so their place does not matter."""
+    start = jnp.minimum(s0, resident.shape[0] - w)
+    e = start + jnp.arange(w)
+    v = lax.dynamic_slice_in_dim(resident, start, w)
+    v = jnp.where(((e >= s0) & (e < s1))[:, None], v, jnp.int8(0))
+    return v.T.astype(dtype)
+
+
+@jax.jit
+def _considered(resident, keep):
+    """Pairs that share an entry ``keep`` marks, off the diagonal. The
+    counts are int32 sums of the int8 incidence, so the test is exact."""
+    v = jnp.where(keep[:, None], resident, jnp.int8(0))
+    n = lax.dot_general(v, resident, (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.int32)
+    return (n > 0) & ~jnp.eye(n.shape[0], dtype=bool)
+
+
 def _bound_stream(idx: InvertedIndex, b: BucketedIndex, acc, l_counts, d_src,
-                  considered, boundable, cfg: CopyConfig, use_timers: bool):
+                  considered, boundable, cfg: CopyConfig, use_timers: bool,
+                  resident=None):
     """Drive the per-bucket step over buckets streamed from the store.
 
-    Gathers one bucket's columns at a time (``store.slice_entries``) —
-    peak incidence residency is a single bucket slice, not (K, S, w).
+    Takes one bucket's columns at a time — out of ``resident`` on the
+    device where given, else from the host (``store.slice_entries``) — so
+    the scan never holds more than one bucket slice, not (K, S, w).
     """
     S = idx.n_sources
     K = b.n_buckets
@@ -173,10 +225,16 @@ def _bound_stream(idx: InvertedIndex, b: BucketedIndex, acc, l_counts, d_src,
     bj = jnp.asarray(boundable)
     for k in range(K):
         s0, s1 = int(starts[k]), int(starts[k + 1])
-        v_np = np.zeros((S, w), np.float32)
-        v_np[:, : s1 - s0] = idx.store.slice_entries(s0, s1, dtype=np.float32)
+        if resident is None:
+            v_np = np.zeros((S, w), np.float32)
+            v_np[:, : s1 - s0] = idx.store.slice_entries(s0, s1,
+                                                         dtype=np.float32)
+            v_k = jnp.asarray(v_np, dt)
+        else:
+            v_k = _bucket_slab(resident, np.int32(s0), np.int32(s1), w=w,
+                               dtype=dt)
         carry = _bound_step(
-            carry, jnp.asarray(v_np, dt), jnp.float32(b.p_hat[k]),
+            carry, v_k, jnp.float32(b.p_hat[k]),
             jnp.float32(b.m_suffix[k + 1]), jnp.float32(deltas[k]),
             jnp.int32(k), accj, lj, dj, cj, bj,
             s=cfg.s, n=cfg.n, theta_cp=cfg.theta_cp, theta_ind=cfg.theta_ind,
@@ -212,25 +270,34 @@ def bound_detect(
     l_counts = idx.l_counts
     d_src = idx.items_per_source
 
-    # considered = co-occurrence outside Ē, accumulated chunk by chunk
-    # (0/1 products in f32 are exact integers, bit-equal to one dense
-    # matmul); the mask form covers committed indexes, where Ē is no longer
-    # a physical suffix (DESIGN.md §7)
-    n_out = idx.store.cooccurrence(mask=idx.nonebar_mask)
-    considered = n_out > 0.5
-    np.fill_diagonal(considered, False)
+    # considered = co-occurrence outside Ē: exact integer counts on either
+    # path (int32 on the device; 0/1 products in f32 chunk by chunk on the
+    # host), so both give the same mask; the mask form covers committed
+    # indexes, where Ē is no longer a physical suffix (DESIGN.md §7)
+    with trace.span("bound.considered") as sp:
+        resident = _resident_incidence(idx.store, S)
+        if resident is None:
+            considered = idx.store.cooccurrence(mask=idx.nonebar_mask) > 0.5
+            np.fill_diagonal(considered, False)
+        else:
+            keep = np.zeros(resident.shape[0], bool)
+            keep[: idx.n_entries] = idx.nonebar_mask
+            considered = np.array(_considered(resident, keep))
+        sp.set(incidence="host" if resident is None else "device")
 
     boundable = idx.l_counts > l_threshold
     np.fill_diagonal(boundable, False)
 
-    (c0, n0, n_full, _nscan, decided, dec_bucket, _md, _xd, err, ve, bc) = \
-        _bound_stream(idx, bucketed, ds.accuracy, l_counts, d_src,
-                      considered, boundable, cfg, use_timers)
-    c0, n0 = np.array(c0), np.array(n0)
-    n_full = np.array(n_full)
-    decided = np.array(decided)
-    dec_bucket = np.array(dec_bucket)
-    err = np.array(err)
+    with trace.span("bound.stream", buckets=K):
+        (c0, n0, n_full, _nscan, decided, dec_bucket, _md, _xd, err, ve,
+         bc) = _bound_stream(idx, bucketed, ds.accuracy, l_counts, d_src,
+                             considered, boundable, cfg, use_timers, resident)
+        del resident                # free the copy before the rescore
+        c0, n0 = np.array(c0), np.array(n0)
+        n_full = np.array(n_full)
+        decided = np.array(decided)
+        dec_bucket = np.array(dec_bucket)
+        err = np.array(err)
 
     lf = idx.l_counts.astype(np.float32)
     # Step IV for still-active pairs (n0 == n_full there): C→ = C^min

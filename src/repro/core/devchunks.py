@@ -20,7 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import Sharding
 
 #: Base chunks written to the resident copy per device call.
 UPLOAD_BATCH = 64
@@ -80,7 +80,7 @@ def _put(buf, blocks, col0):
     return jax.lax.dynamic_update_slice(buf, x, (col0, 0))
 
 
-def upload(store, s_pad: int, sharding: NamedSharding):
+def upload(store, s_pad: int, sharding: Sharding):
     """Ship ``store``'s incidence to the devices of ``sharding``.
 
     Returns ``(resident, nbytes)``: the ``(E_pad, s_pad)`` int8 copy, entry

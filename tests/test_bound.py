@@ -1,11 +1,18 @@
 """BOUND / BOUND+ / HYBRID (§IV) — early termination with the paper's bounds."""
+import time
+
 import numpy as np
 import pytest
 
+from repro.core import devchunks
 from repro.core.bound import bound_detect, hybrid_detect
 from repro.core.bucketed import index_detect_exact
+from repro.core.incremental import make_incremental_state
+from repro.core.index import bucketize, build_index, commit_rows
 from repro.core.scoring import pairwise_detect
-from repro.core.types import CopyConfig, pair_f_measure
+from repro.core.shardplan import shard_store
+from repro.core.types import ClaimsDataset, CopyConfig, pair_f_measure
+from repro.utils import trace
 from repro.data.claims import (
     SyntheticSpec,
     motivating_example,
@@ -85,3 +92,144 @@ def test_chat_bookkeeping_consistency(motivating):
     mask = state.considered & (state.decided == 0)
     # undecided pairs: Ĉ equals the true accumulated C→ (no estimation left)
     np.testing.assert_allclose(state.c_hat[mask], ref.c_fwd[mask], atol=0.05)
+
+
+# -- the incidence read on the device and on the host ------------------------
+
+MODES = {"bound": dict(use_timers=False, l_threshold=0),
+         "bound+": dict(use_timers=True, l_threshold=0),
+         "hybrid": dict(use_timers=True, l_threshold=16)}
+STATE_FIELDS = ("considered", "decided", "dec_bucket", "n_full", "c0", "err")
+
+
+def _corpus(n_sources=70, seed=11):
+    sc = synthetic_claims(SyntheticSpec(n_sources=n_sources, n_items=500,
+                                        coverage="book", n_cliques=5,
+                                        clique_size=3, seed=seed))
+    return sc.dataset, oracle_claim_probs(sc)
+
+
+def _committed():
+    """A base of 60 rows with 10 rows committed: delta chunks, Ē a mask."""
+    ds, p = _corpus()
+    base = ClaimsDataset(values=ds.values[:60], accuracy=ds.accuracy[:60])
+    idx = build_index(base, p[:60], CFG, chunk_entries=64, row_capacity=70)
+    commit_rows(idx, ds, p, CFG, 10, compact=False)
+    assert idx.store.delta_start is not None and idx.ebar_mask is not None
+    return ds, p, idx, bucketize(idx, 64)
+
+
+def _pow2_chunks():
+    """A store whose chunk count is a power of two, so the resident copy
+    has no empty chunk slots, and whose last bucket lies within the widest
+    bucket's width of the copy's end: its slice's start is clamped."""
+    ds, p = _corpus()
+    n = build_index(ds, p, CFG).n_entries
+    for width in range(8, n, 8):
+        chunks = -(-n // width)
+        if chunks < 2 or chunks & (chunks - 1):
+            continue
+        idx = build_index(ds, p, CFG, chunk_entries=width)
+        for k in (64, 16, 8):
+            b = bucketize(idx, k)
+            if b.starts[-2] > chunks * width - np.diff(b.starts).max():
+                assert idx.store.n_chunks == chunks
+                return ds, p, idx, b
+    raise AssertionError("no chunk width clamps the last bucket's slice")
+
+
+def _narrow_last():
+    """Buckets whose last one is narrower than the widest."""
+    ds, p = _corpus()
+    idx = build_index(ds, p, CFG, chunk_entries=64)
+    for k in range(5, 64):
+        b = bucketize(idx, k)
+        widths = np.diff(b.starts)
+        if widths[-1] < widths.max():
+            return ds, p, idx, b
+    raise AssertionError("no bucket count leaves a narrower last bucket")
+
+
+INDEXES = {
+    "fresh": lambda: (*_corpus(), None, None),
+    "committed": _committed,
+    "pow2_chunks": _pow2_chunks,
+    "narrow_last": _narrow_last,
+}
+
+
+def _incidence_paths(monkeypatch, run):
+    """``run()`` on the device path, then on the host path (the copy made
+    not to fit); each result with the incidence its ``bound.considered``
+    span reports."""
+    out = []
+    for fits in (True, False):
+        monkeypatch.setattr(devchunks, "fits", lambda devices, n, f=fits: f)
+        t0 = time.perf_counter()
+        res = run()
+        spans = [r for r in trace.records(t0)
+                 if r.name == "bound.considered"]
+        out.append((res, [r.attrs["incidence"] for r in spans]))
+    monkeypatch.undo()
+    return out
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+@pytest.mark.parametrize("index", sorted(INDEXES))
+def test_device_incidence_matches_host(monkeypatch, index, mode):
+    ds, p, idx, b = INDEXES[index]()
+
+    def run():
+        return bound_detect(ds, p, CFG, index=idx, bucketed=b,
+                            return_state=True, **MODES[mode])
+
+    ((dres, dst), dpath), ((hres, hst), hpath) = \
+        _incidence_paths(monkeypatch, run)
+    assert (dpath, hpath) == (["device"], ["host"])
+    for f in STATE_FIELDS:
+        np.testing.assert_array_equal(getattr(dst, f), getattr(hst, f),
+                                      err_msg=f)
+    np.testing.assert_array_equal(dres.copying, hres.copying)
+    np.testing.assert_array_equal(dres.pr_independent, hres.pr_independent)
+    if mode != "bound":
+        # plain BOUND checks Eq. 10 after every bucket, and its overlap
+        # estimate h can freeze a pair as no-copying early (the paper's
+        # reason for HYBRID): there the host path is the reference
+        exact = index_detect_exact(ds, p, CFG, index=idx)
+        np.testing.assert_array_equal(dres.copying, exact.copying)
+
+
+def test_incremental_bootstrap_device_incidence_matches_host(monkeypatch):
+    ds, p, idx, _ = _committed()
+    ((dres, dst), dpath), ((hres, hst), hpath) = _incidence_paths(
+        monkeypatch,
+        lambda: make_incremental_state(ds, p, CFG, n_buckets=16, index=idx))
+    assert (dpath, hpath) == (["device"], ["host"])
+    for f in ("c_hat", "copying", "considered", "dec_bucket", "err"):
+        np.testing.assert_array_equal(getattr(dst, f), getattr(hst, f),
+                                      err_msg=f)
+    np.testing.assert_array_equal(dres.pr_independent, hres.pr_independent)
+
+
+def test_sharded_store_reads_incidence_on_host():
+    ds, p = _corpus()
+    plain = build_index(ds, p, CFG, chunk_entries=64)
+    sharded = build_index(ds, p, CFG, chunk_entries=64)
+    sharded.store = shard_store(sharded.store, 2)
+    got = {}
+    for name, idx in (("plain", plain), ("sharded", sharded)):
+        t0 = time.perf_counter()
+        got[name] = hybrid_detect(ds, p, CFG, index=idx)
+        recs = trace.records(t0)
+        got[name + "_paths"] = [r.attrs["incidence"] for r in recs
+                                if r.name == "bound.considered"]
+        got[name + "_bytes"] = [r.value for r in recs
+                                if r.name == "bound.h2d_bytes"]
+    assert got["plain_paths"] == ["device"]
+    assert got["sharded_paths"] == ["host"]
+    store = plain.store
+    width = max(c.shape[1] for c in store.chunks)
+    assert got["plain_bytes"] == [store.n_chunks * ds.n_sources * width]
+    assert got["sharded_bytes"] == []
+    np.testing.assert_array_equal(got["plain"].copying,
+                                  got["sharded"].copying)
